@@ -1,5 +1,5 @@
 """Benchmark harness entry point — one section per paper table/figure plus
-the kernel microbenchmarks and the roofline summary.
+the roofline summary.
 
 Prints ``name,us_per_call,derived`` CSV lines (harness contract).
 
@@ -37,8 +37,8 @@ def main(argv=None) -> int:
     windows = 48 if args.quick else (288 if args.full else 96)
 
     from . import (allocator_scaling, extensions, failure_replay, figs,
-                   kernels_bench, risk_scaling, serve_closed_loop,
-                   stage2_scaling, table2, table3, table4, table5, table6)
+                   risk_scaling, serve_closed_loop, stage2_scaling, table2,
+                   table3, table4, table5, table6)
 
     sections = {
         "table2": lambda: table2.run(S=S, include_dm=False,
@@ -68,7 +68,6 @@ def main(argv=None) -> int:
         "serve_closed_loop": lambda: serve_closed_loop.run(quick=args.quick),
         "figs": lambda: figs.run(S=max(20, S // 4)),
         "extensions": extensions.run,
-        "kernels": kernels_bench.run,
         "roofline": _roofline_summary,
     }
     only = set(args.only.split(",")) if args.only else None

@@ -43,6 +43,7 @@ import time
 
 import numpy as np
 
+from ..spans import span
 from .contracts import mutates
 from .gh import _phase1, _phase2, greedy_heuristic
 from .instance import Instance
@@ -494,32 +495,33 @@ def _consolidate_batched(st: State, validate: bool,
     whether any pair was drained."""
     inst = st.inst
     any_improved = False
-    while True:
-        flat = np.flatnonzero((st.q > 0.5).ravel())
-        active = sorted((float(st.y.ravel()[f]), int(f) // inst.K,
-                         int(f) % inst.K) for f in flat)
-        improved = False
-        for _, j, k in active:
-            res = _try_drain_batched(st, j, k, validate)
-            if res is not None:
-                if cache is not None:
-                    # Arm the config diff even when the drained pair had
-                    # no traffic (empty moved-type set): its cfg flipped
-                    # to -1 and the cache must not keep scoring it as an
-                    # active, rental-free destination.
-                    cache.cfg_dirty = True
-                    for t in res[0]:
-                        cache.invalidate_type(t)
-                if clean is not None and clean:
-                    _invalidate_sources(clean, res[0], res[1])
-                if stats is not None:
-                    stats["drains_applied"] = stats.get("drains_applied",
-                                                        0) + 1
-                improved = True
-                break
-        if not improved:
-            return any_improved
-        any_improved = True
+    with span("agh.consolidate"):
+        while True:
+            flat = np.flatnonzero((st.q > 0.5).ravel())
+            active = sorted((float(st.y.ravel()[f]), int(f) // inst.K,
+                             int(f) % inst.K) for f in flat)
+            improved = False
+            for _, j, k in active:
+                res = _try_drain_batched(st, j, k, validate)
+                if res is not None:
+                    if cache is not None:
+                        # Arm the config diff even when the drained pair had
+                        # no traffic (empty moved-type set): its cfg flipped
+                        # to -1 and the cache must not keep scoring it as an
+                        # active, rental-free destination.
+                        cache.cfg_dirty = True
+                        for t in res[0]:
+                            cache.invalidate_type(t)
+                    if clean is not None and clean:
+                        _invalidate_sources(clean, res[0], res[1])
+                    if stats is not None:
+                        stats["drains_applied"] = stats.get("drains_applied",
+                                                            0) + 1
+                    improved = True
+                    break
+            if not improved:
+                return any_improved
+            any_improved = True
 
 
 def _improve_batched(st: State, L: int, validate: bool,

@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from ..spans import span
 from .contracts import mutates
 from .instance import Instance
 from .mechanisms import (State, commit, m3_upgrade, max_commit,
@@ -45,41 +46,42 @@ def _phase1(st: State) -> None:
     else:
         cfg_eff, nm_eff, cover = inst.cfg_m1, inst.m1_nm, inst.cover_ok
     cap = inst.phase1_beta * inst.delta
-    while st.uncovered and st.spend < cap:
-        unc = np.zeros(I, dtype=bool)
-        # repro-lint: ignore[RPR203] -- boolean-mask fill: every index is
-        # set True regardless of visit order, so set order cannot leak.
-        unc[list(st.uncovered)] = True
-        members = cover & unc[:, None, None]              # [I,J,K]
-        cnt = members.sum(axis=0)                         # [J,K]
-        valid = (cnt > 0) & (st.q <= 0.5)
-        if not valid.any():
-            break
-        nm_m = np.where(members, nm_eff, 0)
-        worst_nm = nm_m.max(axis=0)                       # [J,K]
-        # Config of the first (lowest-i) member attaining the max nm —
-        # the scalar scan's `nm > worst_nm` keep-first tie-breaking.
-        first_i = np.argmax(members & (nm_m == worst_nm[None]), axis=0)
-        worst_c = np.take_along_axis(cfg_eff, first_i[None], axis=0)[0]
-        cost = inst.Delta_T * inst.p_c[None, :] * worst_nm   # eq. (14)
-        valid &= st.spend + cost <= cap
-        if inst.avail_gpus is not None:
-            # Phase 1 activates pairs directly (no max_commit): enforce the
-            # shared tier availability cap on the candidate's device count.
-            tier_used = st.y.sum(axis=0)
-            valid &= (tier_used[None, :] + worst_nm
-                      <= inst.avail_gpus[None, :] + 1e-9)
-        if not valid.any():
-            break
-        score = np.full((J, K), -np.inf)
-        score[valid] = cnt[valid] / cost[valid]
-        flat = int(np.argmax(score))                      # first max: j-major
-        j, k = flat // K, flat % K
-        st.q[j, k] = 1.0
-        st.cfg[j, k] = int(worst_c[j, k])
-        st.y[j, k] = int(worst_nm[j, k])
-        st.spend += float(cost[j, k])
-        st.uncovered -= set(int(i) for i in np.flatnonzero(members[:, j, k]))
+    with span("agh.phase1"):
+        while st.uncovered and st.spend < cap:
+            unc = np.zeros(I, dtype=bool)
+            # repro-lint: ignore[RPR203] -- boolean-mask fill: every index is
+            # set True regardless of visit order, so set order cannot leak.
+            unc[list(st.uncovered)] = True
+            members = cover & unc[:, None, None]              # [I,J,K]
+            cnt = members.sum(axis=0)                         # [J,K]
+            valid = (cnt > 0) & (st.q <= 0.5)
+            if not valid.any():
+                break
+            nm_m = np.where(members, nm_eff, 0)
+            worst_nm = nm_m.max(axis=0)                       # [J,K]
+            # Config of the first (lowest-i) member attaining the max nm —
+            # the scalar scan's `nm > worst_nm` keep-first tie-breaking.
+            first_i = np.argmax(members & (nm_m == worst_nm[None]), axis=0)
+            worst_c = np.take_along_axis(cfg_eff, first_i[None], axis=0)[0]
+            cost = inst.Delta_T * inst.p_c[None, :] * worst_nm   # eq. (14)
+            valid &= st.spend + cost <= cap
+            if inst.avail_gpus is not None:
+                # Phase 1 activates pairs directly (no max_commit): enforce the
+                # shared tier availability cap on the candidate's device count.
+                tier_used = st.y.sum(axis=0)
+                valid &= (tier_used[None, :] + worst_nm
+                          <= inst.avail_gpus[None, :] + 1e-9)
+            if not valid.any():
+                break
+            score = np.full((J, K), -np.inf)
+            score[valid] = cnt[valid] / cost[valid]
+            flat = int(np.argmax(score))                      # first max: j-major
+            j, k = flat // K, flat % K
+            st.q[j, k] = 1.0
+            st.cfg[j, k] = int(worst_c[j, k])
+            st.y[j, k] = int(worst_nm[j, k])
+            st.spend += float(cost[j, k])
+            st.uncovered -= set(int(i) for i in np.flatnonzero(members[:, j, k]))
 
 
 def _phase2_prep(st: State, i: int, active: np.ndarray, jj: np.ndarray,
@@ -205,7 +207,8 @@ def _phase2(st: State, order: np.ndarray) -> None:
         # Lazy candidate selection: see `_phase2_walk`.
         kap0 = np.where(valid & (pi == 0), kappa, np.inf).ravel()
         kap1 = np.where(valid & (pi == 1), kappa, np.inf).ravel()
-        jj, kk = _phase2_walk(st, i, c_arr, kap0, kap1, active, jj, kk)
+        with span("agh.phase2.walk"):
+            jj, kk = _phase2_walk(st, i, c_arr, kap0, kap1, active, jj, kk)
 
 
 def greedy_heuristic(inst: Instance, order: np.ndarray | None = None,

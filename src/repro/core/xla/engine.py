@@ -49,6 +49,7 @@ import time
 
 import numpy as np
 
+from ...spans import span
 from ..agh import (_adaptive_R, _assert_state_consistent,
                    _consolidate_batched, _invalidate_sources, _orderings)
 from ..gh import _phase1, _phase2_prep, _phase2_walk
@@ -123,20 +124,22 @@ def _phase2_lockstep(lanes: list[_Lane], tx, batch_width: int | None,
         ln.jj, ln.kk = np.nonzero(ln.active)
     for t in range(inst.I):
         for chunk in _chunked(lanes, batch_width):
-            preps = []
-            for ln in chunk:
-                i = int(ln.order[t])
-                c_arr, d_sel = _phase2_prep(ln.st, i, ln.active, ln.jj,
-                                            ln.kk)
-                preps.append((i, c_arr, d_sel))
-            items = [_phase2_item(ln, i, c_arr, d_sel)
-                     for ln, (i, c_arr, d_sel) in zip(chunk, preps, strict=True)]
+            with span("xla.phase2.pack"):
+                preps = []
+                for ln in chunk:
+                    i = int(ln.order[t])
+                    c_arr, d_sel = _phase2_prep(ln.st, i, ln.active, ln.jj,
+                                                ln.kk)
+                    preps.append((i, c_arr, d_sel))
+                items = [_phase2_item(ln, i, c_arr, d_sel) for ln, (i, c_arr, d_sel)
+                         in zip(chunk, preps, strict=True)]
             kap0, kap1 = kernels.phase2_keys(tx, items, counters)
-            for r, ln in enumerate(chunk):
-                i, c_arr, _ = preps[r]
-                ln.jj, ln.kk = _phase2_walk(ln.st, i, c_arr, kap0[r],
-                                            kap1[r], ln.active, ln.jj,
-                                            ln.kk)
+            with span("agh.phase2.walk"):
+                for r, ln in enumerate(chunk):
+                    i, c_arr, _ = preps[r]
+                    ln.jj, ln.kk = _phase2_walk(ln.st, i, c_arr, kap0[r],
+                                                kap1[r], ln.active, ln.jj,
+                                                ln.kk)
 
 
 # ---------------------------------------------------------------------------
@@ -323,59 +326,61 @@ def _screen_batch(tx, requests: list[tuple], load: np.ndarray,
     src_req: list[tuple] = []
     lane_act: dict[int, tuple] = {}
     screen_stats = counters.get("_screen_stats")
-    for r, (lane_idx, st, obj, sources, rescan) in enumerate(requests):
-        if screen_stats is not None:
-            bucket = screen_stats["rescan" if rescan else "regular"]
-            if not _screen_worthwhile(counters, bucket):
-                counters["screen_bypassed"] = \
-                    counters.get("screen_bypassed", 0) + len(sources)
-                continue    # all-True verdicts: every source scans exactly
-            bucket[0] += len(sources)
-        load[lane_idx] = st.load.reshape(-1)
-        if lane_idx not in lane_act:
-            jj, kk = np.nonzero(st.cfg >= 0)
-            lane_act[lane_idx] = (jj, kk, jj * K + kk,
-                                  inst.nm[st.cfg[jj, kk]].astype(float))
-        jj, kk, a_jk, a_nm = lane_act[lane_idx]
-        # The screen relaxes the exact filters by `slack` so device-side
-        # fusion ulps can only ever add false passes, never false fails.
-        slack = 1e-6 * max(1.0, abs(obj))
-        for n, (i, j, k) in enumerate(sources):
-            key = (lane_idx, i)
-            g = gidx.get(key)
-            if g is None:
-                c_act = st.cfg[jj, kk]
-                d_act = inst.D_cfg[i, jj, kk, c_act]
-                g = gidx[key] = len(groups)
-                groups.append((lane_idx, i,
-                               (st.z[i] < 0.5).reshape(-1), a_jk, a_nm,
-                               d_act, d_act <= inst.Delta[i]))
-            rt = removal_terms(st, i, j, k)
-            base = obj - rt.gain + inst.Delta_T * (inst.p_s * rt.data)
-            rr2, e2, d2 = rt.over[0], rt.over[1], rt.over[2]
-            srcs.append((g, j * K + k,
-                         float(inst.rho[i]) * 1e3 * rt.frac,
-                         (obj - 1e-9) - base + slack,
-                         rr2, inst.eps[i] - e2, inst.Delta[i] - d2,
-                         rt.frac - 1e-9 - slack))
-            src_req.append((r, n))
+    with span("xla.screen.pack"):
+        for r, (lane_idx, st, obj, sources, rescan) in enumerate(requests):
+            if screen_stats is not None:
+                bucket = screen_stats["rescan" if rescan else "regular"]
+                if not _screen_worthwhile(counters, bucket):
+                    counters["screen_bypassed"] = \
+                        counters.get("screen_bypassed", 0) + len(sources)
+                    continue    # all-True verdicts: every source scans exactly
+                bucket[0] += len(sources)
+            load[lane_idx] = st.load.reshape(-1)
+            if lane_idx not in lane_act:
+                jj, kk = np.nonzero(st.cfg >= 0)
+                lane_act[lane_idx] = (jj, kk, jj * K + kk,
+                                      inst.nm[st.cfg[jj, kk]].astype(float))
+            jj, kk, a_jk, a_nm = lane_act[lane_idx]
+            # The screen relaxes the exact filters by `slack` so device-side
+            # fusion ulps can only ever add false passes, never false fails.
+            slack = 1e-6 * max(1.0, abs(obj))
+            for n, (i, j, k) in enumerate(sources):
+                key = (lane_idx, i)
+                g = gidx.get(key)
+                if g is None:
+                    c_act = st.cfg[jj, kk]
+                    d_act = inst.D_cfg[i, jj, kk, c_act]
+                    g = gidx[key] = len(groups)
+                    groups.append((lane_idx, i,
+                                   (st.z[i] < 0.5).reshape(-1), a_jk, a_nm,
+                                   d_act, d_act <= inst.Delta[i]))
+                rt = removal_terms(st, i, j, k)
+                base = obj - rt.gain + inst.Delta_T * (inst.p_s * rt.data)
+                rr2, e2, d2 = rt.over[0], rt.over[1], rt.over[2]
+                srcs.append((g, j * K + k,
+                             float(inst.rho[i]) * 1e3 * rt.frac,
+                             (obj - 1e-9) - base + slack,
+                             rr2, inst.eps[i] - e2, inst.Delta[i] - d2,
+                             rt.frac - 1e-9 - slack))
+                src_req.append((r, n))
     out = [np.ones(len(req[3]), dtype=bool) for req in requests]
     a_max = max((g[3].shape[0] for g in groups), default=0)
     chunk = _SCREEN_CHUNK if a_max <= 1024 else _SCREEN_CHUNK_WIDE
     for lo in range(0, len(srcs), chunk):
-        part = srcs[lo:lo + chunk]
-        # Re-index this chunk's groups compactly so the group axis stays
-        # inside its bucket.
-        remap: dict[int, int] = {}
-        sub_groups: list[tuple] = []
-        sub_srcs: list[tuple] = []
-        for s in part:
-            g = s[0]
-            ng = remap.get(g)
-            if ng is None:
-                ng = remap[g] = len(sub_groups)
-                sub_groups.append(groups[g])
-            sub_srcs.append((ng,) + s[1:])
+        with span("xla.screen.pack"):
+            part = srcs[lo:lo + chunk]
+            # Re-index this chunk's groups compactly so the group axis stays
+            # inside its bucket.
+            remap: dict[int, int] = {}
+            sub_groups: list[tuple] = []
+            sub_srcs: list[tuple] = []
+            for s in part:
+                g = s[0]
+                ng = remap.get(g)
+                if ng is None:
+                    ng = remap[g] = len(sub_groups)
+                    sub_groups.append(groups[g])
+                sub_srcs.append((ng,) + s[1:])
         t0 = time.perf_counter()
         alive = kernels.screen_sources(tx, sub_groups, sub_srcs, load,
                                        counters)
@@ -400,7 +405,8 @@ def _improve_wave(wave: list[_Lane], offset: int, tx, L: int,
         ln.clean = set() if incremental else None
         gen = _improve_lane(ln, L, validate, counters)
         try:
-            req = gen.send(None)
+            with span("agh.relocate"):
+                req = gen.send(None)
             pending.append((offset + idx, ln, gen, req))
         except StopIteration:
             pass
@@ -411,7 +417,8 @@ def _improve_wave(wave: list[_Lane], offset: int, tx, L: int,
         nxt = []
         for (idx, ln, gen, _), v in zip(pending, verdicts, strict=True):
             try:
-                req = gen.send(v)
+                with span("agh.relocate"):
+                    req = gen.send(v)
                 nxt.append((idx, ln, gen, req))
             except StopIteration:
                 pass
@@ -517,61 +524,63 @@ def agh_xla(inst: Instance, R: int | None = None, L: int = 3,
                          "'batched-rescan'")
     del workers   # the lane batch replaces the process pool
     incremental = local_search != "batched-rescan"
-    rng = np.random.default_rng(seed)
-    if R is None:
-        R = _adaptive_R(inst, batched=True)
-    orders = _orderings(inst, R, rng)
-    if priority_orders:
-        orders = [np.asarray(o) for o in priority_orders] + orders
-    tx = tensors_for(inst)
-    counters: dict = {}
-    # Phase 1 is ordering-independent: one run, shared snapshot.
-    st0 = State.fresh(inst)
-    _phase1(st0)
-    p1 = state_snapshot(st0)
-    lanes: list[_Lane] = []
-    if warm_start is not None:
-        lanes.append(_Lane(deployment_state(inst, warm_start),
-                           np.argsort(-inst.lam), is_warm=True))
-    for order in orders:
-        st = State.fresh(inst)
-        state_restore(st, p1)
-        lanes.append(_Lane(st, np.asarray(order)))
+    with span("agh.setup"):
+        rng = np.random.default_rng(seed)
+        if R is None:
+            R = _adaptive_R(inst, batched=True)
+        orders = _orderings(inst, R, rng)
+        if priority_orders:
+            orders = [np.asarray(o) for o in priority_orders] + orders
+        tx = tensors_for(inst)
+        counters: dict = {}
+        # Phase 1 is ordering-independent: one run, shared snapshot.
+        st0 = State.fresh(inst)
+        _phase1(st0)
+        p1 = state_snapshot(st0)
+        lanes: list[_Lane] = []
+        if warm_start is not None:
+            lanes.append(_Lane(deployment_state(inst, warm_start),
+                               np.argsort(-inst.lam), is_warm=True))
+        for order in orders:
+            st = State.fresh(inst)
+            state_restore(st, p1)
+            lanes.append(_Lane(st, np.asarray(order)))
     _phase2_lockstep(lanes, tx, batch_width, counters)
     done = _improve_lockstep(lanes, tx, L, validate, incremental,
                              patience, counters, batch_width)
-    # Deterministic reduction over the improved prefix, in lane order;
-    # the warm lane comes first and therefore wins ties, matching the
-    # numpy warm-start protocol.
-    best, best_obj, best_order, warm_obj = None, np.inf, None, None
-    n_warm = 0
-    for ln in lanes[:done]:
-        obj = state_objective(ln.st)
-        if ln.is_warm:
-            warm_obj = obj
-            n_warm += 1
-        if obj < best_obj - 1e-9:
-            best_obj = obj
-            best = solution_from_state(inst, ln.st)
-            best_order = None if ln.is_warm else ln.order
-    assert best is not None
-    if stats is not None:
-        stats.update(engine="xla", restarts=R,
-                     warm_started=warm_start is not None,
-                     local_search=local_search,
-                     orderings_evaluated=done - n_warm,
-                     early_stopped=done < len(lanes),
-                     winning_order=(None if best_order is None
-                                    else [int(i) for i in best_order]))
-        if warm_obj is not None:
-            stats["warm_objective"] = warm_obj
-        counters.pop("_screen_stats", None)
-        stats.update(counters)
-        for key in ("scan_s", "screen_s"):
-            if key in stats:
-                stats[key] = round(stats[key], 4)
-    assert is_feasible(inst, best, enforce_zeta=False), \
-        "AGH-XLA produced an infeasible solution (engine bug)"
+    with span("agh.reduce"):
+        # Deterministic reduction over the improved prefix, in lane order;
+        # the warm lane comes first and therefore wins ties, matching the
+        # numpy warm-start protocol.
+        best, best_obj, best_order, warm_obj = None, np.inf, None, None
+        n_warm = 0
+        for ln in lanes[:done]:
+            obj = state_objective(ln.st)
+            if ln.is_warm:
+                warm_obj = obj
+                n_warm += 1
+            if obj < best_obj - 1e-9:
+                best_obj = obj
+                best = solution_from_state(inst, ln.st)
+                best_order = None if ln.is_warm else ln.order
+        assert best is not None
+        if stats is not None:
+            stats.update(engine="xla", restarts=R,
+                         warm_started=warm_start is not None,
+                         local_search=local_search,
+                         orderings_evaluated=done - n_warm,
+                         early_stopped=done < len(lanes),
+                         winning_order=(None if best_order is None
+                                        else [int(i) for i in best_order]))
+            if warm_obj is not None:
+                stats["warm_objective"] = warm_obj
+            counters.pop("_screen_stats", None)
+            stats.update(counters)
+            for key in ("scan_s", "screen_s"):
+                if key in stats:
+                    stats[key] = round(stats[key], 4)
+        assert is_feasible(inst, best, enforce_zeta=False), \
+            "AGH-XLA produced an infeasible solution (engine bug)"
     best.runtime_s = time.perf_counter() - t0
     best.method = "AGH-XLA"
     return best

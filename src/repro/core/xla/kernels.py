@@ -30,6 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ...spans import span
 from .tensors import XlaInstanceTensors
 
 
@@ -48,6 +49,11 @@ def _pad2(rows: list[np.ndarray], n_rows: int, n_cols: int, fill,
     for r, a in enumerate(rows):
         out[r, : a.shape[0]] = a
     return out
+
+
+def _count(counters: dict | None, key: str, n: int) -> None:
+    if counters is not None:
+        counters[key] = counters.get(key, 0) + n
 
 
 # ---------------------------------------------------------------------------
@@ -105,25 +111,35 @@ def phase2_keys(tx: XlaInstanceTensors, items: list[tuple],
     """
     JK = tx.JK
     R = len(items)
-    a_max = max((it[5].shape[0] for it in items), default=0)
-    A = _bucket(max(a_max, 1), _ACT_STEPS, JK)
-    i_idx = np.fromiter((it[0] for it in items), np.int64, R)
-    y = np.stack([it[1] for it in items])
-    rr = np.fromiter((it[2] for it in items), np.float64, R)
-    E = np.fromiter((it[3] for it in items), np.float64, R)
-    D = np.fromiter((it[4] for it in items), np.float64, R)
-    act_jk = _pad2([it[5] for it in items], R, A, JK, np.int64)
-    act_cost = _pad2([it[6] for it in items], R, A, 0.0, np.float64)
-    act_d = _pad2([it[7] for it in items], R, A, 0.0, np.float64)
-    act_valid = _pad2([it[8] for it in items], R, A, False, bool)
-    kap0, kap1 = _phase2_keys_jit(
-        tx.m1_nm, tx.psb_data, tx.rho_d, tx.m1_delay, tx.m1_valid, tx.ebf,
-        tx.pc_flat, tx.eps, tx.Delta, tx.Delta_T, i_idx, y, rr, E, D,
-        act_jk, act_cost, act_d, act_valid)
-    if counters is not None:
-        counters["device_calls_phase2"] = \
-            counters.get("device_calls_phase2", 0) + 1
-    return np.array(kap0), np.array(kap1)
+    with span("xla.phase2.pack"):
+        a_max = max((it[5].shape[0] for it in items), default=0)
+        A = _bucket(max(a_max, 1), _ACT_STEPS, JK)
+        i_idx = np.fromiter((it[0] for it in items), np.int64, R)
+        y = np.stack([it[1] for it in items])
+        rr = np.fromiter((it[2] for it in items), np.float64, R)
+        E = np.fromiter((it[3] for it in items), np.float64, R)
+        D = np.fromiter((it[4] for it in items), np.float64, R)
+        act_jk = _pad2([it[5] for it in items], R, A, JK, np.int64)
+        act_cost = _pad2([it[6] for it in items], R, A, 0.0, np.float64)
+        act_d = _pad2([it[7] for it in items], R, A, 0.0, np.float64)
+        act_valid = _pad2([it[8] for it in items], R, A, False, bool)
+    per_call = (i_idx, y, rr, E, D, act_jk, act_cost, act_d, act_valid)
+    with span("xla.phase2.dispatch"):
+        kap0, kap1 = _phase2_keys_jit(
+            tx.m1_nm, tx.psb_data, tx.rho_d, tx.m1_delay, tx.m1_valid,
+            tx.ebf, tx.pc_flat, tx.eps, tx.Delta, tx.Delta_T, *per_call)
+        # Queue the copies behind the program, so that the explicit wait
+        # adds no round trip of its own before the fetch.
+        kap0.copy_to_host_async()
+        kap1.copy_to_host_async()
+    with span("xla.phase2.wait"):
+        jax.block_until_ready((kap0, kap1))
+    with span("xla.phase2.fetch"):
+        kap0, kap1 = np.array(kap0), np.array(kap1)
+    _count(counters, "device_calls_phase2", 1)
+    _count(counters, "upload_bytes", sum(a.nbytes for a in per_call))
+    _count(counters, "readback_bytes", kap0.nbytes + kap1.nbytes)
+    return kap0, kap1
 
 
 # ---------------------------------------------------------------------------
@@ -192,7 +208,31 @@ def screen_sources(tx: XlaInstanceTensors, groups: list[tuple],
     per-solve constant).  Returns a bool verdict per real source
     (True = may improve, run the exact scan).
     """
-    JK = tx.JK
+    nS = len(srcs)
+    with span("xla.screen.pack"):
+        per_call = _screen_inputs(tx.JK, groups, srcs, load)
+    with span("xla.screen.dispatch"):
+        alive = _screen_jit(tx.m1_delay, tx.m1_valid, tx.m1_rental,
+                            tx.m1_nm, tx.ebf, tx.lpx, tx.psB_flat,
+                            tx.comp_flat, tx.Delta_T, *per_call)
+        real = alive[:nS]
+        real.copy_to_host_async()
+    with span("xla.screen.wait"):
+        real.block_until_ready()
+    with span("xla.screen.fetch"):
+        verdicts = np.array(real)
+    _count(counters, "device_calls_screen", 1)
+    _count(counters, "screen_sources", nS)
+    _count(counters, "screen_rows_padded", int(alive.shape[0]))
+    _count(counters, "upload_bytes", sum(a.nbytes for a in per_call))
+    _count(counters, "readback_bytes", verdicts.nbytes)
+    return verdicts
+
+
+def _screen_inputs(JK: int, groups: list[tuple], srcs: list[tuple],
+                   load: np.ndarray) -> tuple[np.ndarray, ...]:
+    """The screen program's per-call arguments, padded to their
+    buckets (see `screen_sources`)."""
     nG, nS = len(groups), len(srcs)
     a_max = max((g[3].shape[0] for g in groups), default=0)
     A = _bucket(max(a_max, 1), _SCREEN_ACT_STEPS, JK)
@@ -228,14 +268,5 @@ def screen_sources(tx: XlaInstanceTensors, groups: list[tuple],
         s_g[s], s_jk[s] = g, jk
         dyn[s], bound[s], rr2[s] = dy, bd, r2
         err_num[s], del_num[s], fthr[s] = en, dn, ft
-    alive = _screen_jit(tx.m1_delay, tx.m1_valid, tx.m1_rental, tx.m1_nm,
-                        tx.ebf, tx.lpx, tx.psB_flat, tx.comp_flat,
-                        tx.Delta_T, g_i, g_lane, z_lt, act_jk, act_nm,
-                        act_d, act_ok, load, s_g, s_jk, dyn, bound, rr2,
-                        err_num, del_num, fthr)
-    if counters is not None:
-        counters["device_calls_screen"] = \
-            counters.get("device_calls_screen", 0) + 1
-        counters["screen_sources"] = \
-            counters.get("screen_sources", 0) + nS
-    return np.array(alive[:nS])
+    return (g_i, g_lane, z_lt, act_jk, act_nm, act_d, act_ok, load, s_g,
+            s_jk, dyn, bound, rr2, err_num, del_num, fthr)

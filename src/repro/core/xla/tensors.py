@@ -25,6 +25,7 @@ jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402  (after the x64 switch, deliberately)
 
+from ...spans import span  # noqa: E402
 from ..instance import Instance  # noqa: E402
 
 
@@ -79,6 +80,7 @@ class XlaInstanceTensors:
 
 def tensors_for(inst: Instance) -> XlaInstanceTensors:
     """The instance's cached tensor bundle, built on first use."""
-    if inst._xla_tensors is None:
-        inst._xla_tensors = XlaInstanceTensors(inst)
-    return inst._xla_tensors
+    with span("xla.tensors"):
+        if inst._xla_tensors is None:
+            inst._xla_tensors = XlaInstanceTensors(inst)
+        return inst._xla_tensors

@@ -28,6 +28,7 @@ import time
 from repro.core.instance import Instance
 from repro.core.solution import (Solution, _constraint_usage, cost_terms,
                                  feasibility, objective, slack_report)
+from repro.spans import span
 
 from .registry import get_solver
 
@@ -218,25 +219,26 @@ def plan(request: PlanRequest | str | None = None, *,
           or engine is not None):
         raise ValueError("pass either a PlanRequest or keyword fields, "
                          "not both")
-    spec = get_solver(request.solver)
-    inst = request.resolve_instance()
-    warm = request.warm_start if spec.supports_warm_start else None
-    t0, c0 = time.perf_counter(), time.process_time()
-    sol, diag = spec.solve(inst, request.options, warm)
-    wall, cpu = time.perf_counter() - t0, time.process_time() - c0
-    diag = dict(diag)
-    if request.warm_start is not None:
-        diag.setdefault("warm_started", spec.supports_warm_start)
-    result = build_result(spec.name, inst, sol, wall, cpu, diag,
-                          request.options)
-    if request.options.risk is not None:
-        # Post-pass tail-risk evaluation of the solved plan.  Lazy
-        # import: plans without risk= never touch repro.risk (nor jax).
-        from repro.risk import risk_evaluate
-        report = risk_evaluate(inst, result.solution,
-                               **request.options.risk)
-        result.diagnostics["risk"] = report.summary()
-    return result
+    with span("facade.plan"):
+        spec = get_solver(request.solver)
+        inst = request.resolve_instance()
+        warm = request.warm_start if spec.supports_warm_start else None
+        t0, c0 = time.perf_counter(), time.process_time()
+        sol, diag = spec.solve(inst, request.options, warm)
+        wall, cpu = time.perf_counter() - t0, time.process_time() - c0
+        diag = dict(diag)
+        if request.warm_start is not None:
+            diag.setdefault("warm_started", spec.supports_warm_start)
+        result = build_result(spec.name, inst, sol, wall, cpu, diag,
+                              request.options)
+        if request.options.risk is not None:
+            # Post-pass tail-risk evaluation of the solved plan.  Lazy
+            # import: plans without risk= never touch repro.risk (nor jax).
+            from repro.risk import risk_evaluate
+            report = risk_evaluate(inst, result.solution,
+                                   **request.options.risk)
+            result.diagnostics["risk"] = report.summary()
+        return result
 
 
 def build_result(solver: str, inst: Instance, sol: Solution, wall_s: float,
@@ -253,12 +255,13 @@ def build_result(solver: str, inst: Instance, sol: Solution, wall_s: float,
     zeta-violating plan is reported infeasible here yet still operable.)
     One shared usage pass feeds both the violation and slack views.
     """
-    usage = _constraint_usage(inst, sol)
-    viol = feasibility(inst, sol, enforce_zeta=True, usage=usage)
-    return PlanResult(
-        solver=solver, solution=sol, objective=objective(inst, sol),
-        cost_breakdown=cost_terms(inst, sol),
-        slack=slack_report(inst, sol, usage=usage), violations=viol,
-        feasible=all(v <= 1e-4 for v in viol.values()),
-        wall_s=wall_s, cpu_s=cpu_s, diagnostics=diagnostics,
-        options=options.to_dict())
+    with span("facade.result"):
+        usage = _constraint_usage(inst, sol)
+        viol = feasibility(inst, sol, enforce_zeta=True, usage=usage)
+        return PlanResult(
+            solver=solver, solution=sol, objective=objective(inst, sol),
+            cost_breakdown=cost_terms(inst, sol),
+            slack=slack_report(inst, sol, usage=usage), violations=viol,
+            feasible=all(v <= 1e-4 for v in viol.values()),
+            wall_s=wall_s, cpu_s=cpu_s, diagnostics=diagnostics,
+            options=options.to_dict())

@@ -40,6 +40,7 @@ from repro.core.faults import FaultSchedule, apply_faults
 from repro.core.forecast import relative_drift
 from repro.core.instance import Instance
 from repro.core.solution import Solution
+from repro.spans import span
 
 from .api import PlanOptions, PlanRequest, PlanResult, build_result, plan
 from .registry import get_solver
@@ -123,38 +124,39 @@ class PlanSession:
         ``cause=`` tags the `replan_log` row (the serving controller
         passes its trigger cause — "drift"/"slo"/"scheduled").
         """
-        if lam is not None:
-            if instance is not None or scenario is not None:
-                raise ValueError("pass lam= alone, or instance=/scenario=")
-            if self.last_instance is None:
-                raise ValueError("lam= replan needs a prior plan()/replan() "
-                                 "on a full instance")
-            instance = self.last_instance.with_lam(np.asarray(lam, float))
-        inst = self._resolve(instance, scenario)
-        if (self.incumbent is None
-                or self.incumbent.x.shape != (inst.I, inst.J, inst.K)):
-            # No incumbent, or one from a differently-shaped problem
-            # (population changed): nothing to warm-start from.
-            return self.plan(instance=inst)
-        warm = get_solver(self.solver).supports_warm_start
-        opts = self.options
-        if warm:
-            # Fast-replan protocol: tighter patience, no random restarts,
-            # and the incumbent's winning ordering replayed first (the
-            # multi-start winner is empirically stable under drift — see
-            # core/agh.py `priority_orders`).  The sequential driver is
-            # pinned unless the caller set workers explicitly: AGH's
-            # auto fan-out evaluates EVERY ordering with no early stop,
-            # which would silently discard the patience the warm seed
-            # buys — exactly at the fleet scales where auto engages.
-            opts = dataclasses.replace(
-                opts, patience=self.replan_patience,
-                restarts=self.replan_restarts, order=self.winning_order,
-                workers=0 if opts.workers is None else opts.workers)
-        res = plan(PlanRequest(solver=self.solver, instance=inst,
-                               options=opts, warm_start=self.incumbent))
-        self._install(inst, res, warm=warm, kind="replan", cause=cause)
-        return res
+        with span("facade.replan"):
+            if lam is not None:
+                if instance is not None or scenario is not None:
+                    raise ValueError("pass lam= alone, or instance=/scenario=")
+                if self.last_instance is None:
+                    raise ValueError("lam= replan needs a prior plan()/replan() "
+                                     "on a full instance")
+                instance = self.last_instance.with_lam(np.asarray(lam, float))
+            inst = self._resolve(instance, scenario)
+            if (self.incumbent is None
+                    or self.incumbent.x.shape != (inst.I, inst.J, inst.K)):
+                # No incumbent, or one from a differently-shaped problem
+                # (population changed): nothing to warm-start from.
+                return self.plan(instance=inst)
+            warm = get_solver(self.solver).supports_warm_start
+            opts = self.options
+            if warm:
+                # Fast-replan protocol: tighter patience, no random restarts,
+                # and the incumbent's winning ordering replayed first (the
+                # multi-start winner is empirically stable under drift — see
+                # core/agh.py `priority_orders`).  The sequential driver is
+                # pinned unless the caller set workers explicitly: AGH's
+                # auto fan-out evaluates EVERY ordering with no early stop,
+                # which would silently discard the patience the warm seed
+                # buys — exactly at the fleet scales where auto engages.
+                opts = dataclasses.replace(
+                    opts, patience=self.replan_patience,
+                    restarts=self.replan_restarts, order=self.winning_order,
+                    workers=0 if opts.workers is None else opts.workers)
+            res = plan(PlanRequest(solver=self.solver, instance=inst,
+                                   options=opts, warm_start=self.incumbent))
+            self._install(inst, res, warm=warm, kind="replan", cause=cause)
+            return res
 
     def drift(self, lam: np.ndarray) -> float:
         """Demand-weighted relative L1 drift of `lam` against the rates
@@ -199,62 +201,63 @@ class PlanSession:
         (``level`` 0–3, rung ``name``, the ``ladder`` rungs attempted,
         and the residual violation families) — an infeasible repair is
         never silent: its level is >= 1 with a non-empty report."""
-        if instance is None and scenario is None:
-            if self.last_instance is None:
-                raise ValueError("repair() without instance=/scenario= "
-                                 "needs a prior plan()/replan()")
-            inst = self.last_instance
-        else:
-            inst = self._resolve(instance, scenario)
-        if schedule is not None:
-            inst = apply_faults(inst, schedule, t)
-        t0, c0 = time.perf_counter(), time.process_time()
-        sol, diag, warm = self._repair_solve(inst, passes)
-        evicted = [list(map(int, jk)) for jk in diag.get("evicted", [])]
-        res = build_result(self.solver, inst, sol, 0.0, 0.0, dict(diag),
-                           self.options)
-        level, name = 0, "strict"
-        tried = ["strict"]
-        if not res.feasible:
-            level, name = 1, "unmet-cap"
-            tried.append("delay-relax")
-            relaxed = dataclasses.replace(
-                inst, Delta=inst.Delta * self.repair_delay_relax)
-            cand = self._ladder_retry(inst, relaxed, res, passes)
-            base = inst
-            if cand is not None:
-                res, base = cand, relaxed
-                level, name = 2, "delay-relax"
-            if not res.feasible:
-                tried.append("budget-overdraft")
-                overdrawn = dataclasses.replace(
-                    base, delta=inst.delta * self.repair_budget_overdraft)
-                cand = self._ladder_retry(inst, overdrawn, res, passes)
-                if cand is not None:
-                    res = cand
-                    level, name = 3, "budget-overdraft"
-        if res.feasible:
-            # A ladder retry may land a plan that satisfies the REAL
-            # constraint system outright — then nothing was degraded.
+        with span("facade.repair"):
+            if instance is None and scenario is None:
+                if self.last_instance is None:
+                    raise ValueError("repair() without instance=/scenario= "
+                                     "needs a prior plan()/replan()")
+                inst = self.last_instance
+            else:
+                inst = self._resolve(instance, scenario)
+            if schedule is not None:
+                inst = apply_faults(inst, schedule, t)
+            t0, c0 = time.perf_counter(), time.process_time()
+            sol, diag, warm = self._repair_solve(inst, passes)
+            evicted = [list(map(int, jk)) for jk in diag.get("evicted", [])]
+            res = build_result(self.solver, inst, sol, 0.0, 0.0, dict(diag),
+                               self.options)
             level, name = 0, "strict"
-        res.wall_s = time.perf_counter() - t0
-        res.cpu_s = time.process_time() - c0
-        res.diagnostics["repair"] = {
-            "evicted": evicted, "warm": warm, "wall_s": res.wall_s,
-            "degradation": {
-                "level": level, "name": name, "ladder": tried,
-                "violations": {k: float(v)
-                               for k, v in res.violations.items()
-                               if v > 1e-4},
-                "unmet_excess": _unmet_excess(inst, res.solution),
-                "zeta_overshoot": float(
-                    res.violations.get("unmet_cap", 0.0)),
-                "budget_overdraft": float(
-                    res.violations.get("budget", 0.0)),
-            }}
-        self._install(inst, res, warm=warm, kind="repair", cause=cause)
-        self.repairs += 1
-        return res
+            tried = ["strict"]
+            if not res.feasible:
+                level, name = 1, "unmet-cap"
+                tried.append("delay-relax")
+                relaxed = dataclasses.replace(
+                    inst, Delta=inst.Delta * self.repair_delay_relax)
+                cand = self._ladder_retry(inst, relaxed, res, passes)
+                base = inst
+                if cand is not None:
+                    res, base = cand, relaxed
+                    level, name = 2, "delay-relax"
+                if not res.feasible:
+                    tried.append("budget-overdraft")
+                    overdrawn = dataclasses.replace(
+                        base, delta=inst.delta * self.repair_budget_overdraft)
+                    cand = self._ladder_retry(inst, overdrawn, res, passes)
+                    if cand is not None:
+                        res = cand
+                        level, name = 3, "budget-overdraft"
+            if res.feasible:
+                # A ladder retry may land a plan that satisfies the REAL
+                # constraint system outright — then nothing was degraded.
+                level, name = 0, "strict"
+            res.wall_s = time.perf_counter() - t0
+            res.cpu_s = time.process_time() - c0
+            res.diagnostics["repair"] = {
+                "evicted": evicted, "warm": warm, "wall_s": res.wall_s,
+                "degradation": {
+                    "level": level, "name": name, "ladder": tried,
+                    "violations": {k: float(v)
+                                   for k, v in res.violations.items()
+                                   if v > 1e-4},
+                    "unmet_excess": _unmet_excess(inst, res.solution),
+                    "zeta_overshoot": float(
+                        res.violations.get("unmet_cap", 0.0)),
+                    "budget_overdraft": float(
+                        res.violations.get("budget", 0.0)),
+                }}
+            self._install(inst, res, warm=warm, kind="repair", cause=cause)
+            self.repairs += 1
+            return res
 
     def _repair_solve(self, inst: Instance,
                       passes: int) -> tuple[Solution, dict, bool]:

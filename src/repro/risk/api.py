@@ -29,6 +29,7 @@ import numpy as np
 from ..core.instance import Instance
 from ..core.solution import Solution, provisioning_cost
 from ..core.stage2 import Stage2System
+from ..spans import span
 from .metrics import ALPHAS, risk_stats
 
 ENGINES = ("pdhg", "exact")
@@ -127,49 +128,55 @@ def risk_evaluate(inst: Instance, deploy: Solution, S: int = 20_000,
     e_infl = PROTOCOL["e_infl"] if e_infl is None else e_infl
     lam_pm = PROTOCOL["lam_pm"] if lam_pm is None else lam_pm
 
-    t0 = time.perf_counter()
-    system = Stage2System(inst, deploy)
-    if engine == "pdhg":
-        from .solver import BatchedStage2Solver  # lazy: pulls in jax
-        solver = BatchedStage2Solver(system, max_anchors=max_anchors)
-        solve_chunk = solver.solve_scenarios
-    else:
-        from .solver_exact import ExactChunkSolver
-        solver = ExactChunkSolver(system)
-        solve_chunk = solver.solve_scenarios
+    with span("facade.risk"):
+        t0 = time.perf_counter()
+        with span("stage2.build"):
+            system = Stage2System(inst, deploy)
+            if engine == "pdhg":
+                from .solver import BatchedStage2Solver  # lazy: pulls in jax
+                solver = BatchedStage2Solver(system, max_anchors=max_anchors)
+            else:
+                from .solver_exact import ExactChunkSolver
+                solver = ExactChunkSolver(system)
 
-    rng = np.random.default_rng(seed)
-    costs = np.zeros(S)
-    viols = np.zeros(S, dtype=np.int64)
-    unmet = np.zeros(S)
-    util = np.zeros((S, len(Stage2System.ROW_FAMILIES)))
-    done = 0
-    for batch in inst.perturbed_chunks(rng, S, chunk=chunk, d_infl=d_infl,
-                                       e_infl=e_infl, lam_pm=lam_pm):
-        out = solve_chunk(batch)
-        sl = slice(done, done + batch.S)
-        costs[sl] = out.costs
-        viols[sl] = out.viols
-        unmet[sl] = out.unmet
-        util[sl] = out.util
-        done += batch.S
-    wall = time.perf_counter() - t0
+        rng = np.random.default_rng(seed)
+        costs = np.zeros(S)
+        viols = np.zeros(S, dtype=np.int64)
+        unmet = np.zeros(S)
+        util = np.zeros((S, len(Stage2System.ROW_FAMILIES)))
+        done = 0
+        chunks = inst.perturbed_chunks(rng, S, chunk=chunk, d_infl=d_infl,
+                                       e_infl=e_infl, lam_pm=lam_pm)
+        while True:
+            with span("stage2.draw"):
+                batch = next(chunks, None)
+            if batch is None:
+                break
+            out = solver.solve_scenarios(batch)
+            sl = slice(done, done + batch.S)
+            costs[sl] = out.costs
+            viols[sl] = out.viols
+            unmet[sl] = out.unmet
+            util[sl] = out.util
+            done += batch.S
+        wall = time.perf_counter() - t0
 
-    s1 = provisioning_cost(inst, deploy)
-    stats = risk_stats(s1 + costs, viols, unmet, util,
-                       Stage2System.ROW_FAMILIES, alphas=alphas,
-                       tail_alpha=tail_alpha)
-    diag = dict(solver.diagnostics)
-    diag["n_anchors"] = len(getattr(solver, "anchors", ()))
-    return RiskReport(
-        method=deploy.method, engine=engine, S=S, stage1_cost=float(s1),
-        expected_cost=stats["expected_cost"], cost_std=stats["cost_std"],
-        var=stats["var"], cvar=stats["cvar"],
-        violation_rate=stats["viol_total"] / (S * inst.I),
-        viol_quantiles=stats["viol_quantiles"],
-        unmet_quantiles=stats["unmet_quantiles"],
-        tail_attribution=stats["tail_attribution"],
-        diagnostics=diag, wall_s=float(wall), stage2_costs=costs)
+        with span("stage2.stats"):
+            s1 = provisioning_cost(inst, deploy)
+            stats = risk_stats(s1 + costs, viols, unmet, util,
+                               Stage2System.ROW_FAMILIES, alphas=alphas,
+                               tail_alpha=tail_alpha)
+        diag = dict(solver.diagnostics)
+        diag["n_anchors"] = len(getattr(solver, "anchors", ()))
+        return RiskReport(
+            method=deploy.method, engine=engine, S=S, stage1_cost=float(s1),
+            expected_cost=stats["expected_cost"], cost_std=stats["cost_std"],
+            var=stats["var"], cvar=stats["cvar"],
+            violation_rate=stats["viol_total"] / (S * inst.I),
+            viol_quantiles=stats["viol_quantiles"],
+            unmet_quantiles=stats["unmet_quantiles"],
+            tail_attribution=stats["tail_attribution"],
+            diagnostics=diag, wall_s=float(wall), stage2_costs=costs)
 
 
 def rank_deployments(inst: Instance, deployments: dict[str, Solution],

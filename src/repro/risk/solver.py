@@ -70,6 +70,7 @@ import jax.numpy as jnp  # noqa: E402  (after the x64 switch, deliberately)
 
 from ..core.instance import ScenarioBatch  # noqa: E402
 from ..core.stage2 import Stage2System  # noqa: E402
+from ..spans import span  # noqa: E402
 from .solver_exact import ExactChunkSolver, _ChunkArrays  # noqa: E402
 
 # PDHG convergence criteria — the single correctness authority for every
@@ -368,6 +369,8 @@ class BatchedStage2Solver(ExactChunkSolver):
         self.diagnostics = {
             "n_anchor0": 0, "n_harvest_exact": 0, "n_pdhg": 0,
             "n_fallback_exact": 0, "pdhg_iters_max": 0, "n_scenarios": 0,
+            "upload_bytes": 0, "readback_bytes": 0, "candidate_rows": 0,
+            "candidate_rows_padded": 0,
         }
         # Static device-side pattern tensors, shared by every kernel call.
         f64 = jnp.float64
@@ -562,6 +565,17 @@ class BatchedStage2Solver(ExactChunkSolver):
                 jnp.asarray(act_mask, dtype=f64),
                 jnp.asarray(B0inv, dtype=f64), jnp.asarray(G0, dtype=f64))
 
+    def _upload(self, a: np.ndarray, dtype) -> jax.Array:
+        """One per-call host-to-device copy, counted."""
+        self.diagnostics["upload_bytes"] += a.nbytes
+        return jnp.asarray(a, dtype=dtype)
+
+    def _fetch(self, a: jax.Array) -> np.ndarray:
+        """One device-to-host read-back, counted."""
+        host = np.asarray(a)
+        self.diagnostics["readback_bytes"] += host.nbytes
+        return host
+
     # -- scenario features (anchor ordering only; no correctness role) --
     def _features(self, batch: ScenarioBatch) -> np.ndarray:
         inst = self.system.inst
@@ -579,28 +593,32 @@ class BatchedStage2Solver(ExactChunkSolver):
     def solve_scenarios(self, batch: ScenarioBatch) -> _ChunkArrays:
         system = self.system
         S = batch.S
-        vals, c = system.coefficient_batch(batch)
-        feats = self._features(batch)
+        with span("stage2.coefficients"):
+            vals, c = system.coefficient_batch(batch)
+            feats = self._features(batch)
         out = _ChunkArrays(S, self.n_fam)
         diag = self.diagnostics
         diag["n_scenarios"] += S
 
         if not self.anchors:
-            v0, c0 = system.coefficient_batch(ScenarioBatch(S=1))
+            with span("stage2.coefficients"):
+                v0, c0 = system.coefficient_batch(ScenarioBatch(S=1))
             res0 = self._exact(v0[0], c0[0])
-            self._harvest_anchor(res0, v0[0],
-                                 np.ones_like(self._feat_base))
+            with span("stage2.record"):
+                self._harvest_anchor(res0, v0[0],
+                                     np.ones_like(self._feat_base))
 
         # One chunk-wide device residency (padded to a bucket so chunk
         # length doesn't multiply kernel compiles); per-group rows are
         # gathered on device, inside the kernel's jit.
-        Scb = _bucket(S)
-        vals_p = np.zeros((Scb, vals.shape[1]))
-        vals_p[:S] = vals
-        c_p = np.zeros((Scb, c.shape[1]))
-        c_p[:S] = c
-        d_vals_all = jnp.asarray(vals_p, dtype=jnp.float64)
-        d_c_all = jnp.asarray(c_p, dtype=jnp.float64)
+        with span("stage2.upload"):
+            Scb = _bucket(S)
+            vals_p = np.zeros((Scb, vals.shape[1]))
+            vals_p[:S] = vals
+            c_p = np.zeros((Scb, c.shape[1]))
+            c_p[:S] = c
+            d_vals_all = self._upload(vals_p, jnp.float64)
+            d_c_all = self._upload(c_p, jnp.float64)
         feat_sq = np.sum(feats * feats, axis=1)
 
         unresolved = np.arange(S)
@@ -612,40 +630,42 @@ class BatchedStage2Solver(ExactChunkSolver):
         while unresolved.size:
             A = len(self.anchors)
             still: list[np.ndarray] = []
-            if A == 0:
-                # No kernel-representable anchor yet (every harvested
-                # basis tripped every _SHAPE_CLASSES cap): skip the anchor
-                # pass — the harvest/PDHG tail below sees everything
-                # exhausted and keeps making progress one exact solve
-                # (or one PDHG batch) at a time.
-                exhausted_idx = unresolved
-                live = pick = np.zeros(0, dtype=np.int64)
-            else:
-                if tried.shape[1] < A:
-                    tried = np.concatenate(
-                        [tried, np.zeros((S, A - tried.shape[1]), bool)],
-                        axis=1)
-                # Anchor ordering (heuristic only — never affects
-                # correctness): first pass goes to the nearest
-                # hit-centroid, retries walk the untried anchors by hit
-                # frequency.
-                afeat = np.stack([a.centroid for a in self.anchors])
-                hits = np.array([a.hits for a in self.anchors], dtype=float)
-                t_u = tried[unresolved]
-                fu = feats[unresolved]
-                dist = (feat_sq[unresolved, None]
-                        + np.sum(afeat * afeat, axis=1)[None, :]
-                        - 2.0 * (fu @ afeat.T))
-                dist[t_u] = np.inf
-                hit_score = np.where(t_u, -np.inf, hits[None, :])
-                first = ~t_u.any(axis=1)
-                pick = np.where(first, np.argmin(dist, axis=1),
-                                np.argmax(hit_score, axis=1))
-                exhausted = ~np.isfinite(
-                    dist[np.arange(unresolved.size), pick])
-                exhausted_idx = unresolved[exhausted]
-                live = unresolved[~exhausted]
-                pick = pick[~exhausted]
+            with span("stage2.assign"):
+                if A == 0:
+                    # No kernel-representable anchor yet (every harvested
+                    # basis tripped every _SHAPE_CLASSES cap): skip the
+                    # anchor pass — the harvest/PDHG tail below sees
+                    # everything exhausted and keeps making progress one
+                    # exact solve (or one PDHG batch) at a time.
+                    exhausted_idx = unresolved
+                    live = pick = np.zeros(0, dtype=np.int64)
+                else:
+                    if tried.shape[1] < A:
+                        tried = np.concatenate(
+                            [tried, np.zeros((S, A - tried.shape[1]), bool)],
+                            axis=1)
+                    # Anchor ordering (heuristic only — never affects
+                    # correctness): first pass goes to the nearest
+                    # hit-centroid, retries walk the untried anchors by
+                    # hit frequency.
+                    afeat = np.stack([a.centroid for a in self.anchors])
+                    hits = np.array([a.hits for a in self.anchors],
+                                    dtype=float)
+                    t_u = tried[unresolved]
+                    fu = feats[unresolved]
+                    dist = (feat_sq[unresolved, None]
+                            + np.sum(afeat * afeat, axis=1)[None, :]
+                            - 2.0 * (fu @ afeat.T))
+                    dist[t_u] = np.inf
+                    hit_score = np.where(t_u, -np.inf, hits[None, :])
+                    first = ~t_u.any(axis=1)
+                    pick = np.where(first, np.argmin(dist, axis=1),
+                                    np.argmax(hit_score, axis=1))
+                    exhausted = ~np.isfinite(
+                        dist[np.arange(unresolved.size), pick])
+                    exhausted_idx = unresolved[exhausted]
+                    live = unresolved[~exhausted]
+                    pick = pick[~exhausted]
 
             for a_id in np.unique(pick):
                 grp = live[pick == a_id]
@@ -656,36 +676,55 @@ class BatchedStage2Solver(ExactChunkSolver):
                 # scenarios actually trying this anchor.
                 Sg = grp.size
                 Sb = _bucket(Sg)
-                pad = np.concatenate([grp, np.repeat(grp[:1], Sb - Sg)])
-                d_pad = jnp.asarray(pad, dtype=jnp.int64)
-                ok, p, z, y, rowsv, score = _candidate_kernel(
-                    d_vals_all, d_c_all, d_pad,
-                    self._d_rhs0, self._d_is_eq,
-                    self._d_rows, self._d_cols, self._d_ub,
-                    self._d_Rm, self._d_Rn, *anchor.pack)
-                ok_np = np.asarray(ok)[:Sg]
-                hit = grp[ok_np]
-                z_np = None
-                if hit.size:
-                    anchor.hits += int(hit.size)
-                    anchor.feat_sum += feats[hit].sum(axis=0)
-                    diag["n_anchor0"] += int(hit.size)
-                    out.costs[hit] = np.asarray(p)[:Sg][ok_np]
-                    z_np = np.asarray(z)[:Sg]
-                    rows_np = np.asarray(rowsv)[:Sg]
-                    out.record_batch(hit, z_np[ok_np], rows_np[ok_np], self)
-                miss = grp[~ok_np]
-                if miss.size:
-                    sc = np.asarray(score)[:Sg][~ok_np]
-                    better = sc < best_score[miss]
-                    upd = miss[better]
-                    if upd.size:
-                        if z_np is None:
-                            z_np = np.asarray(z)[:Sg]
-                        best_score[upd] = sc[better]
-                        best_z[upd] = z_np[~ok_np][better]
-                        best_y[upd] = np.asarray(y)[:Sg][~ok_np][better]
-                    still.append(miss)
+                diag["candidate_rows"] += Sg
+                diag["candidate_rows_padded"] += Sb
+                with span("stage2.candidate.dispatch"):
+                    pad = np.concatenate([grp, np.repeat(grp[:1], Sb - Sg)])
+                    d_pad = self._upload(pad, jnp.int64)
+                    ok, p, z, y, rowsv, score = _candidate_kernel(
+                        d_vals_all, d_c_all, d_pad,
+                        self._d_rhs0, self._d_is_eq,
+                        self._d_rows, self._d_cols, self._d_ub,
+                        self._d_Rm, self._d_Rn, *anchor.pack)
+                    # Queued behind the program: the wait adds no round
+                    # trip of its own before the fetch.
+                    ok.copy_to_host_async()
+                with span("stage2.candidate.wait"):
+                    ok.block_until_ready()
+                # Read back only what the host uses: costs and primal/row
+                # values of the hits, scores of the misses, and the
+                # iterates of misses that improve their best score.
+                with span("stage2.candidate.fetch"):
+                    ok_np = self._fetch(ok)[:Sg]
+                    hit = grp[ok_np]
+                    miss = grp[~ok_np]
+                    z_np = None
+                    if hit.size:
+                        p_np = self._fetch(p)[:Sg]
+                        z_np = self._fetch(z)[:Sg]
+                        rows_np = self._fetch(rowsv)[:Sg]
+                    if miss.size:
+                        sc = self._fetch(score)[:Sg][~ok_np]
+                        better = sc < best_score[miss]
+                        upd = miss[better]
+                        if upd.size:
+                            if z_np is None:
+                                z_np = self._fetch(z)[:Sg]
+                            y_np = self._fetch(y)[:Sg]
+                with span("stage2.record"):
+                    if hit.size:
+                        anchor.hits += int(hit.size)
+                        anchor.feat_sum += feats[hit].sum(axis=0)
+                        diag["n_anchor0"] += int(hit.size)
+                        out.costs[hit] = p_np[ok_np]
+                        out.record_batch(hit, z_np[ok_np], rows_np[ok_np],
+                                         self)
+                    if miss.size:
+                        if upd.size:
+                            best_score[upd] = sc[better]
+                            best_z[upd] = z_np[~ok_np][better]
+                            best_y[upd] = y_np[~ok_np][better]
+                        still.append(miss)
 
             leftovers = (np.concatenate(still) if still
                          else np.zeros(0, dtype=np.int64))
@@ -696,8 +735,9 @@ class BatchedStage2Solver(ExactChunkSolver):
                     s = int(exhausted_idx[0])
                     res = self._exact(vals[s], c[s])
                     diag["n_harvest_exact"] += 1
-                    self._record_exact(s, vals[s], c[s], res, out)
-                    self._harvest_anchor(res, vals[s], feats[s])
+                    with span("stage2.record"):
+                        self._record_exact(s, vals[s], c[s], res, out)
+                        self._harvest_anchor(res, vals[s], feats[s])
                     unresolved = np.concatenate(
                         [leftovers, exhausted_idx[1:]])
                     continue
@@ -717,50 +757,59 @@ class BatchedStage2Solver(ExactChunkSolver):
         diag = self.diagnostics
         if not idx.size:
             return
-        Sp = idx.size
-        Sb = _bucket(Sp)
-        pad = np.concatenate([idx, np.repeat(idx[:1], Sb - Sp)])
-        d_vals = jnp.asarray(vals[pad], dtype=jnp.float64)
-        d_c = jnp.asarray(c[pad], dtype=jnp.float64)
-        d_z0 = jnp.asarray(best_z[pad], dtype=jnp.float64)
-        d_y0 = jnp.asarray(best_y[pad], dtype=jnp.float64)
-        (vs, cs, rhss, ubs, sig0, tau0, omega, dr, dc, z, y) = _pdhg_setup(
-            d_vals, d_c, self._d_rhs0, self._d_rows, self._d_cols,
-            self._d_ub, d_z0, d_y0)
-        z_r, y_r = z, y
-        n_inner = jnp.asarray(self.pdhg_check, dtype=jnp.int64)
-        done = np.zeros(Sb, dtype=bool)
-        p_done = np.zeros(Sb)
-        z_done = np.zeros((Sb, self.n))
-        it = 0
-        while it < self.pdhg_max_iter:
-            z, y, omega, p, pf, gap = _pdhg_block(
-                vs, cs, rhss, ubs, sig0, tau0, self._d_is_eq,
-                self._d_rows, self._d_cols, self._d_Rm, self._d_Rn,
-                dr, omega, z, y, z_r, y_r, n_inner)
+        with span("stage2.pdhg"):
+            Sp = idx.size
+            Sb = _bucket(Sp)
+            pad = np.concatenate([idx, np.repeat(idx[:1], Sb - Sp)])
+            f64 = jnp.float64
+            d_vals = self._upload(vals[pad], f64)
+            d_c = self._upload(c[pad], f64)
+            d_z0 = self._upload(best_z[pad], f64)
+            d_y0 = self._upload(best_y[pad], f64)
+            (vs, cs, rhss, ubs, sig0, tau0, omega, dr, dc, z,
+             y) = _pdhg_setup(d_vals, d_c, self._d_rhs0, self._d_rows,
+                              self._d_cols, self._d_ub, d_z0, d_y0)
             z_r, y_r = z, y
-            it += self.pdhg_check
-            ok = np.asarray((pf < TOL_PF) & (gap < TOL_GAP))
-            new = ok & ~done
-            if new.any():
-                p_np = np.asarray(p)
-                z_phys = np.asarray(z * dc)
-                p_done[new] = p_np[new]
-                z_done[new] = z_phys[new]
-                done |= new
-            if done[:Sp].all():
-                break
-        diag["pdhg_iters_max"] = max(diag["pdhg_iters_max"], it)
-        conv = np.where(done[:Sp])[0]
-        if conv.size:
-            diag["n_pdhg"] += int(conv.size)
-            sel = idx[conv]
-            out.costs[sel] = p_done[conv]
-            for j, s in zip(conv, sel, strict=True):
-                out.record_z(int(s), vals[s], z_done[j], self)
+            n_inner = jnp.asarray(self.pdhg_check, dtype=jnp.int64)
+            done = np.zeros(Sb, dtype=bool)
+            p_done = np.zeros(Sb)
+            z_done = np.zeros((Sb, self.n))
+            it = 0
+            while it < self.pdhg_max_iter:
+                z, y, omega, p, pf, gap = _pdhg_block(
+                    vs, cs, rhss, ubs, sig0, tau0, self._d_is_eq,
+                    self._d_rows, self._d_cols, self._d_Rm, self._d_Rn,
+                    dr, omega, z, y, z_r, y_r, n_inner)
+                z_r, y_r = z, y
+                it += self.pdhg_check
+                conv = (pf < TOL_PF) & (gap < TOL_GAP)
+                conv.copy_to_host_async()
+                with span("stage2.pdhg.wait"):
+                    conv.block_until_ready()
+                with span("stage2.pdhg.fetch"):
+                    ok = self._fetch(conv)
+                new = ok & ~done
+                if new.any():
+                    with span("stage2.pdhg.fetch"):
+                        p_np = self._fetch(p)
+                        z_phys = self._fetch(z * dc)
+                    p_done[new] = p_np[new]
+                    z_done[new] = z_phys[new]
+                    done |= new
+                if done[:Sp].all():
+                    break
+            diag["pdhg_iters_max"] = max(diag["pdhg_iters_max"], it)
+            conv = np.where(done[:Sp])[0]
+            if conv.size:
+                diag["n_pdhg"] += int(conv.size)
+                sel = idx[conv]
+                out.costs[sel] = p_done[conv]
+                for j, s in zip(conv, sel, strict=True):
+                    out.record_z(int(s), vals[s], z_done[j], self)
         fail = np.where(~done[:Sp])[0]
         for j in fail:
             s = int(idx[j])
             res = self._exact(vals[s], c[s])
             diag["n_fallback_exact"] += 1
-            self._record_exact(s, vals[s], c[s], res, out)
+            with span("stage2.record"):
+                self._record_exact(s, vals[s], c[s], res, out)

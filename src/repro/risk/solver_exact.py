@@ -19,6 +19,7 @@ from scipy.optimize import linprog
 
 from ..core.instance import ScenarioBatch
 from ..core.stage2 import Stage2System
+from ..spans import span
 
 
 class _ChunkArrays:
@@ -74,12 +75,13 @@ class ExactChunkSolver:
 
     def _exact(self, vals: np.ndarray, c: np.ndarray):
         """One exact scenario solve via linprog/HiGHS (exposes duals)."""
-        K = sparse.coo_matrix((vals, (self.rows, self.cols)),
-                              shape=(self.m, self.n)).tocsr()
-        bounds = np.stack([np.zeros(self.n), self.ub], axis=1)
-        return linprog(c, A_ub=K[:self.m_ub], b_ub=self.rhs0[:self.m_ub],
-                       A_eq=K[self.m_ub:], b_eq=self.rhs0[self.m_ub:],
-                       bounds=bounds, method="highs")
+        with span("oracle.exact"):
+            K = sparse.coo_matrix((vals, (self.rows, self.cols)),
+                                  shape=(self.m, self.n)).tocsr()
+            bounds = np.stack([np.zeros(self.n), self.ub], axis=1)
+            return linprog(c, A_ub=K[:self.m_ub], b_ub=self.rhs0[:self.m_ub],
+                           A_eq=K[self.m_ub:], b_eq=self.rhs0[self.m_ub:],
+                           bounds=bounds, method="highs")
 
     def _record_exact(self, s: int, vals: np.ndarray, c: np.ndarray, res,
                       out: _ChunkArrays) -> None:
