@@ -49,6 +49,16 @@ cheapest first:
    iteration budget fall back to the exact oracle and are *counted* in
    the diagnostics — never silently dropped.
 
+What crosses back to the host: each candidate call reads back one packed
+array, [S, 4 + n_fam] f64 (verdict, cost, violation count, unmet total
+and per-family utilization of every scenario of the group), which the
+kernel reduces from the candidate's primal and row values itself
+(`_ChunkArrays._stats` is the definition).  The iterates never leave the
+device: each scenario's best miss candidate lives in one chunk-resident
+device array, [Scb, 1 + n + m] (score, primal, dual), that every
+candidate call updates in place through one gather, and PDHG gathers its
+warm starts from it.
+
 The LP solved here is the relaxed Stage-2 protocol (u <= 1, always
 feasible), matching `Stage2System.solve(u_cap=ones)` — the risk
 statistics want the realized cost of every scenario, not a strict-cap
@@ -58,6 +68,7 @@ rtol 1e-5 (in practice ~1e-14); pinned in tests/test_risk.py.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 from scipy import sparse
@@ -150,14 +161,30 @@ def _solve_small_t(Mlu, r):
     return a
 
 
-@jax.jit
-def _candidate_kernel(vals_all, c_all, pad, rhs0, is_eq, rows_a, cols_a,
-                      ub, Rm, Rn,
+@functools.partial(jax.jit, donate_argnames=("best",))
+def _candidate_kernel(vals_all, c_all, idx, rhs0, is_eq, rows_a, cols_a,
+                      ub, Rm, Rn, u_col, fam_mask, rhs_safe, best,
                       e_r, m_r, M_r, rhs_act,
                       scale_e, scale_m, scale_mask,
                       e_g, dv0, jpos_g, rowq_g, Hq, Hk, P_M, Hg,
                       bas_idx, bas_mask, nb_vec, act_idx, act_mask,
                       B0inv, G0):
+    """One anchor's candidates for a group of chunk rows, verified and
+    summarized.
+
+    `idx` [S + Scb] int32 is one upload of two index vectors: `pad`, the
+    group's chunk rows padded to the bucket S by repeating the first, then
+    `inv`, each chunk row's position in the group, or S where it is not
+    in it.  Returns ``(score, summary, best, z, y, rowsv)``.  The host
+    reads back `summary` alone, [S, 4 + n_fam] f64 with columns ok, p,
+    viols, unmet, util[0..n_fam): the cost and the statistics
+    `_ChunkArrays._stats` defines, computed here from `z` and `rowsv`
+    (`u_col` marks the unmet columns, `fam_mask` [m, n_fam] the rows of
+    each family, `rhs_safe` the clamped right-hand sides).  `best`
+    [Scb, 1 + n + m], donated, holds each chunk row's best miss candidate
+    so far: its score (inf while there is none), z, then y.  `score`,
+    `z`, `y` and `rowsv` are outputs for the tests only.
+    """
     # All index-space reductions here are (gather, one-hot matmul) pairs
     # rather than `.at[].add` scatters: XLA CPU lowers batched scatters
     # to a serial per-index loop (~ms per call at S=8192), while the
@@ -167,6 +194,7 @@ def _candidate_kernel(vals_all, c_all, pad, rhs0, is_eq, rows_a, cols_a,
     # The group gather (pad -> rows of the chunk-resident tensors) lives
     # INSIDE the jit: done outside, each gather pays ~ms of trace and
     # dispatch overhead per call.
+    pad, inv = idx[:-vals_all.shape[0]], idx[-vals_all.shape[0]:]
     vals = vals_all[pad]
     c = c_all[pad]
     S = pad.shape[0]
@@ -214,7 +242,26 @@ def _candidate_kernel(vals_all, c_all, pad, rhs0, is_eq, rows_a, cols_a,
     gap = jnp.where(jnp.isfinite(gap), gap, jnp.inf)
     ok = (pf < TOL_PF) & (gap < TOL_GAP)
     score = jnp.maximum(pf, gap)
-    return ok, p, z, y, rowsv, score
+
+    # Summary: what the host keeps of each scenario.
+    u = jnp.where(u_col[None, :], jnp.clip(z, 0.0, 1.0), 0.0)
+    viols = jnp.sum(u > 0.01, axis=1).astype(vals.dtype)
+    unmet = jnp.sum(u, axis=1)
+    ratio = rowsv / rhs_safe[None, :]
+    util = jnp.max(jnp.where(fam_mask[None, :, :], ratio[:, :, None],
+                             -jnp.inf), axis=1)
+    util = jnp.where(jnp.any(fam_mask, axis=0)[None, :], util, 0.0)
+    summary = jnp.concatenate(
+        [ok.astype(vals.dtype)[:, None], p[:, None], viols[:, None],
+         unmet[:, None], util], axis=1)
+
+    # Warm starts for PDHG: one gather from the group, never a scatter,
+    # of each row's candidate packed as [score (inf on hits), z, y].
+    cand = jnp.concatenate([jnp.where(ok, jnp.inf, score)[:, None], z, y],
+                           axis=1)[jnp.clip(inv, 0, S - 1)]
+    better = (inv < S) & (cand[:, 0] < best[:, 0])
+    best = jnp.where(better[:, None], cand, best)
+    return score, summary, best, z, y, rowsv
 
 
 # ---------------------------------------------------------------------------
@@ -223,10 +270,16 @@ def _candidate_kernel(vals_all, c_all, pad, rhs0, is_eq, rows_a, cols_a,
 # ---------------------------------------------------------------------------
 
 @jax.jit
-def _pdhg_setup(vals, c, rhs0, rows_a, cols_a, ub, z0, y0):
+def _pdhg_setup(vals, c, rhs0, rows_a, cols_a, ub, best, pad):
     S, nnz = vals.shape
     m = rhs0.shape[0]
     n = c.shape[1]
+    # Warm start: each row's best candidate (`_candidate_kernel`), zeros
+    # where no candidate has a finite score.
+    w = best[pad]
+    have = jnp.isfinite(w[:, :1])
+    z0 = jnp.where(have, w[:, 1:1 + n], 0.0)
+    y0 = jnp.where(have, w[:, 1 + n:], 0.0)
     vs = vals
     dr = jnp.ones((S, m), dtype=vals.dtype)
     dc = jnp.ones((S, n), dtype=vals.dtype)
@@ -352,7 +405,9 @@ class BatchedStage2Solver(ExactChunkSolver):
     large S resolve almost entirely at iteration 0.  Thread-compatible
     with the relaxed Stage-2 protocol only (u_cap is pinned to ones).
     The exact oracle, the pattern plumbing, and the statistics recorder
-    come from `ExactChunkSolver` — both engines share them verbatim.
+    come from `ExactChunkSolver`; scenarios settled on the device have
+    their statistics computed by `_candidate_kernel` to the definition of
+    `_ChunkArrays._stats` (pinned in tests/test_risk.py).
     """
 
     def __init__(self, system: Stage2System, *, max_anchors: int = 32,
@@ -388,6 +443,16 @@ class BatchedStage2Solver(ExactChunkSolver):
         Rn[np.arange(E), self.cols] = 1.0
         self._d_Rm = jnp.asarray(Rm, dtype=f64)
         self._d_Rn = jnp.asarray(Rn, dtype=f64)
+        # What the candidate kernel's summary reads (`_ChunkArrays._stats`):
+        # the unmet columns, each family's inequality rows, and the
+        # right-hand sides clamped away from zero.
+        u_col = np.arange(self.n) >= self.nx
+        fam_mask = np.zeros((self.m, self.n_fam), dtype=bool)
+        fam_mask[np.arange(self.m_ub), system.row_family] = True
+        self._d_u_col = jnp.asarray(u_col, dtype=jnp.bool_)
+        self._d_fam_mask = jnp.asarray(fam_mask, dtype=jnp.bool_)
+        self._d_rhs_safe = jnp.asarray(np.maximum(self.rhs0, 1e-12),
+                                       dtype=f64)
 
     def _harvest_anchor(self, res, vals: np.ndarray, feat: np.ndarray
                         ) -> bool:
@@ -623,9 +688,10 @@ class BatchedStage2Solver(ExactChunkSolver):
 
         unresolved = np.arange(S)
         tried = np.zeros((S, 0), dtype=bool)
-        best_score = np.full(S, np.inf)
-        best_z = np.zeros((S, self.n))
-        best_y = np.zeros((S, self.m))
+        # Each scenario's best candidate so far, for PDHG's warm start:
+        # chunk-resident, updated inside every candidate call.
+        best = jnp.full((Scb, 1 + self.n + self.m), jnp.inf,
+                        dtype=jnp.float64)
 
         while unresolved.size:
             A = len(self.anchors)
@@ -679,52 +745,40 @@ class BatchedStage2Solver(ExactChunkSolver):
                 diag["candidate_rows"] += Sg
                 diag["candidate_rows_padded"] += Sb
                 with span("stage2.candidate.dispatch"):
-                    pad = np.concatenate([grp, np.repeat(grp[:1], Sb - Sg)])
-                    d_pad = self._upload(pad, jnp.int64)
-                    ok, p, z, y, rowsv, score = _candidate_kernel(
-                        d_vals_all, d_c_all, d_pad,
+                    # [pad | inv] (see _candidate_kernel), one upload.
+                    idx = np.full(Sb + Scb, Sb, dtype=np.int32)
+                    idx[:Sb] = grp[0]
+                    idx[:Sg] = grp
+                    idx[Sb + grp] = np.arange(Sg)
+                    d_idx = self._upload(idx, jnp.int32)
+                    _, summary, best, *_ = _candidate_kernel(
+                        d_vals_all, d_c_all, d_idx,
                         self._d_rhs0, self._d_is_eq,
                         self._d_rows, self._d_cols, self._d_ub,
-                        self._d_Rm, self._d_Rn, *anchor.pack)
+                        self._d_Rm, self._d_Rn, self._d_u_col,
+                        self._d_fam_mask, self._d_rhs_safe, best,
+                        *anchor.pack)
                     # Queued behind the program: the wait adds no round
                     # trip of its own before the fetch.
-                    ok.copy_to_host_async()
+                    summary.copy_to_host_async()
                 with span("stage2.candidate.wait"):
-                    ok.block_until_ready()
-                # Read back only what the host uses: costs and primal/row
-                # values of the hits, scores of the misses, and the
-                # iterates of misses that improve their best score.
+                    summary.block_until_ready()
+                # One array per call: verdict, cost and statistics of
+                # each scenario; misses keep their iterates on the device.
                 with span("stage2.candidate.fetch"):
-                    ok_np = self._fetch(ok)[:Sg]
-                    hit = grp[ok_np]
-                    miss = grp[~ok_np]
-                    z_np = None
-                    if hit.size:
-                        p_np = self._fetch(p)[:Sg]
-                        z_np = self._fetch(z)[:Sg]
-                        rows_np = self._fetch(rowsv)[:Sg]
-                    if miss.size:
-                        sc = self._fetch(score)[:Sg][~ok_np]
-                        better = sc < best_score[miss]
-                        upd = miss[better]
-                        if upd.size:
-                            if z_np is None:
-                                z_np = self._fetch(z)[:Sg]
-                            y_np = self._fetch(y)[:Sg]
+                    summ = self._fetch(summary)[:Sg]
                 with span("stage2.record"):
+                    ok_np = summ[:, 0] != 0.0
+                    hit = grp[ok_np]
                     if hit.size:
                         anchor.hits += int(hit.size)
                         anchor.feat_sum += feats[hit].sum(axis=0)
                         diag["n_anchor0"] += int(hit.size)
-                        out.costs[hit] = p_np[ok_np]
-                        out.record_batch(hit, z_np[ok_np], rows_np[ok_np],
-                                         self)
-                    if miss.size:
-                        if upd.size:
-                            best_score[upd] = sc[better]
-                            best_z[upd] = z_np[~ok_np][better]
-                            best_y[upd] = y_np[~ok_np][better]
-                        still.append(miss)
+                        sh = summ[ok_np]
+                        out.costs[hit] = sh[:, 1]
+                        out.record_stats(hit, sh[:, 2], sh[:, 3], sh[:, 4:])
+                    if not ok_np.all():
+                        still.append(grp[~ok_np])
 
             leftovers = (np.concatenate(still) if still
                          else np.zeros(0, dtype=np.int64))
@@ -744,16 +798,19 @@ class BatchedStage2Solver(ExactChunkSolver):
                 # Anchor space exhausted: hand the rest to PDHG.
                 unresolved = np.zeros(0, dtype=np.int64)
                 pdhg_idx = np.concatenate([leftovers, exhausted_idx])
-                self._run_pdhg(pdhg_idx, vals, c, best_z, best_y, out)
+                self._run_pdhg(pdhg_idx, vals, c, best, out)
                 return out
             unresolved = leftovers
 
         return out
 
     def _run_pdhg(self, idx: np.ndarray, vals: np.ndarray, c: np.ndarray,
-                  best_z: np.ndarray, best_y: np.ndarray,
-                  out: _ChunkArrays) -> None:
-        """Phase 2 (restarted PDHG) + phase 3 (exact fallback)."""
+                  best: jax.Array, out: _ChunkArrays) -> None:
+        """Phase 2 (restarted PDHG) + phase 3 (exact fallback).
+
+        `best` holds the chunk's best candidates on the device
+        (`_candidate_kernel`); the warm starts are gathered from it there.
+        """
         diag = self.diagnostics
         if not idx.size:
             return
@@ -764,11 +821,10 @@ class BatchedStage2Solver(ExactChunkSolver):
             f64 = jnp.float64
             d_vals = self._upload(vals[pad], f64)
             d_c = self._upload(c[pad], f64)
-            d_z0 = self._upload(best_z[pad], f64)
-            d_y0 = self._upload(best_y[pad], f64)
+            d_pad = self._upload(pad, jnp.int64)
             (vs, cs, rhss, ubs, sig0, tau0, omega, dr, dc, z,
              y) = _pdhg_setup(d_vals, d_c, self._d_rhs0, self._d_rows,
-                              self._d_cols, self._d_ub, d_z0, d_y0)
+                              self._d_cols, self._d_ub, best, d_pad)
             z_r, y_r = z, y
             n_inner = jnp.asarray(self.pdhg_check, dtype=jnp.int64)
             done = np.zeros(Sb, dtype=bool)

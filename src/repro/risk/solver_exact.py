@@ -3,8 +3,10 @@
 Pure numpy/scipy — `risk_evaluate(engine="exact")` routes here and never
 imports jax.  `BatchedStage2Solver` (the pdhg engine) subclasses
 `ExactChunkSolver` to share the LP pattern plumbing, the linprog oracle,
-and the per-scenario statistics recorder, guaranteeing both engines
-compute cost/violation/utilization through the SAME code.
+and the per-scenario statistics recorder.  `_ChunkArrays._stats` is the
+one definition of the violation/unmet/utilization statistics: the pdhg
+engine's device kernel computes them to it (pinned in
+tests/test_risk.py) and hands them over through `record_stats`.
 
 The LP solved is the relaxed Stage-2 protocol (u <= 1): always feasible,
 so every scenario yields a realized cost — what the tail statistics
@@ -37,9 +39,13 @@ class _ChunkArrays:
         np.add.at(rowsv, solver.rows, vals * z[solver.cols])
         self._stats(np.array([s]), z[None, :], rowsv[None, :], solver)
 
-    def record_batch(self, sel: np.ndarray, z: np.ndarray,
-                     rowsv: np.ndarray, solver: "ExactChunkSolver") -> None:
-        self._stats(sel, z, rowsv, solver)
+    def record_stats(self, sel: np.ndarray, viols: np.ndarray,
+                     unmet: np.ndarray, util: np.ndarray) -> None:
+        """Statistics `_stats` defines, computed elsewhere (the pdhg
+        engine's candidate kernel computes them on the device)."""
+        self.viols[sel] = viols
+        self.unmet[sel] = unmet
+        self.util[sel] = util
 
     def _stats(self, sel: np.ndarray, z: np.ndarray, rowsv: np.ndarray,
                solver: "ExactChunkSolver") -> None:

@@ -121,10 +121,13 @@ def pdhg_sizes():
 def test_candidate_kernel_compiles(one_chip, risk_sizes, q, eg):
     E, m, n = risk_sizes
     S, K = _bucket(8192), n
+    n_fam = len(Stage2System.ROW_FAMILIES)
     _compile(_candidate_kernel, [
-        ((S, E), F64), ((S, n), F64), ((S,), I64), ((m,), F64), ((m,), B8),
-        ((E,), I64), ((E,), I64), ((n,), F64), ((E, m), F64),
+        ((S, E), F64), ((S, n), F64), ((2 * S,), jnp.int32), ((m,), F64),
+        ((m,), B8), ((E,), I64), ((E,), I64), ((n,), F64), ((E, m), F64),
         ((E, n), F64),
+        # the summary's masks and the donated best candidates
+        ((n,), B8), ((m, n_fam), B8), ((m,), F64), ((S, 1 + n + m), F64),
         # the anchor pack (`BatchedStage2Solver._pack`)
         ((E,), I64), ((E,), F64), ((E, K), F64), ((K,), F64),
         ((K,), I64), ((K,), F64), ((K,), F64),
@@ -139,7 +142,7 @@ def test_pdhg_setup_compiles(one_chip, pdhg_sizes):
     S = _bucket(120)
     _compile(_pdhg_setup, [
         ((S, E), F64), ((S, n), F64), ((m,), F64), ((E,), I64), ((E,), I64),
-        ((n,), F64), ((S, n), F64), ((S, m), F64)], one_chip)
+        ((n,), F64), ((S, 1 + n + m), F64), ((S,), I64)], one_chip)
 
 
 def test_pdhg_block_compiles(one_chip, pdhg_sizes):

@@ -14,6 +14,8 @@ identity both engines consume, CVaR against a hand-computed value, the
 report JSON round trip, the planner `risk=` hook, and the invariant-lint
 scopes covering `src/repro/risk/`.
 """
+import inspect
+
 import numpy as np
 import pytest
 
@@ -23,7 +25,7 @@ from repro.core.stage2 import HAVE_HIGHSPY, Stage2System
 from repro.risk import RiskReport, rank_deployments, risk_evaluate
 from repro.risk.api import PROTOCOL
 from repro.risk.metrics import var_cvar
-from repro.risk.solver_exact import ExactChunkSolver
+from repro.risk.solver_exact import ExactChunkSolver, _ChunkArrays
 
 jax = pytest.importorskip("jax")
 from repro.risk.solver import BatchedStage2Solver  # noqa: E402
@@ -59,14 +61,19 @@ def test_pdhg_matches_oracle_per_scenario(inst, deploy):
     np.testing.assert_allclose(out_pd.costs, out_ex.costs, rtol=RTOL)
 
 
-def test_pdhg_matches_oracle_stressed_wide_bases():
+@pytest.fixture(scope="module")
+def stressed():
+    """The 1.5x-stressed (20,20,20) instance and its AGH deployment."""
+    big = random_instance(20, 20, 20, seed=42)
+    return big.stressed(1.5), agh(big)
+
+
+def test_pdhg_matches_oracle_stressed_wide_bases(stressed):
     """1.5x stress activates 15-16 delay/error rows per optimal basis —
     only representable through the wide (q, eg) shape class.  Before the
     shape classes every anchor was rejected and the whole batch fell to
     per-scenario exact solves; anchors > 0 pins the fix."""
-    big = random_instance(20, 20, 20, seed=42)
-    sinst = big.stressed(1.5)
-    dep = agh(big)
+    sinst, dep = stressed
     S = 160
     batch = _batch(sinst, S)
     system = Stage2System(sinst, dep)
@@ -78,14 +85,48 @@ def test_pdhg_matches_oracle_stressed_wide_bases():
     assert solver.diagnostics["n_anchor0"] > 0
 
 
-def test_forced_pdhg_path_and_diagnostics_accounting(inst, deploy):
+def _logged_solve(system, batch, **kw):
+    """Solve `batch`, keeping host copies of every candidate call's
+    inputs (by name) and outputs."""
+    from repro.risk import solver as solver_mod
+    real = solver_mod._candidate_kernel
+    params = list(inspect.signature(real).parameters)
+    calls = []
+
+    def logged(*args):
+        named = {k: np.asarray(a) for k, a in zip(params, args)
+                 if k in ("idx", "Hq")}
+        out = real(*args)
+        call = dict(named, **{k: np.asarray(v) for k, v in zip(
+            ("score", "summary", "best", "z", "y", "rowsv"), out)})
+        call["inv"] = call["idx"][call["score"].size:]
+        calls.append(call)
+        return out
+
+    solver = BatchedStage2Solver(system, **kw)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(solver_mod, "_candidate_kernel", logged)
+        out = solver.solve_scenarios(batch)
+    return solver, out, calls
+
+
+@pytest.fixture(scope="module")
+def forced(inst, deploy):
+    """max_anchors=0 freezes the anchor set at the seed anchor, forcing
+    every miss through restarted PDHG (phase 2)."""
+    S = 120
+    batch = _batch(inst, S, seed=5)
+    solver, out, calls = _logged_solve(Stage2System(inst, deploy), batch,
+                                       max_anchors=0)
+    return batch, solver, out, calls
+
+
+def test_forced_pdhg_path_and_diagnostics_accounting(inst, deploy, forced):
     """max_anchors=0 freezes the anchor set at the seed anchor, forcing
     every miss through restarted PDHG (phase 2) — and every scenario must
     be accounted for in exactly one diagnostics bucket."""
     S = 120
-    batch = _batch(inst, S, seed=5)
-    solver = BatchedStage2Solver(Stage2System(inst, deploy), max_anchors=0)
-    out = solver.solve_scenarios(batch)
+    batch, solver, out, _ = forced
     d = solver.diagnostics
     assert d["n_scenarios"] == S
     assert (d["n_anchor0"] + d["n_harvest_exact"] + d["n_pdhg"]
@@ -94,6 +135,69 @@ def test_forced_pdhg_path_and_diagnostics_accounting(inst, deploy):
     out_ex = ExactChunkSolver(Stage2System(inst, deploy)) \
         .solve_scenarios(batch)
     np.testing.assert_allclose(out.costs, out_ex.costs, rtol=RTOL)
+
+
+def test_warm_starts_are_each_miss_best_candidate(forced):
+    """The device-resident warm starts PDHG begins from are, per miss, the
+    lowest-scoring candidate over the anchors it tried (the first of
+    equal scores), recomputed here on the host from each call's
+    candidates.  From them PDHG settles 118 scenarios, the budget of
+    20,000 iterations is reached, and 2 fall back to the exact oracle."""
+    _, solver, _, calls = forced
+    Scb = calls[0]["inv"].size
+    best_score = np.full(Scb, np.inf)
+    best_z = np.zeros((Scb, solver.n))
+    best_y = np.zeros((Scb, solver.m))
+    for call in calls:
+        rows = np.where(call["inv"] < call["score"].size)[0]
+        j = call["inv"][rows]
+        ok = call["summary"][j, 0] != 0.0
+        sc = call["score"][j]
+        upd = ~ok & (sc < best_score[rows])
+        best_score[rows[upd]] = sc[upd]
+        best_z[rows[upd]] = call["z"][j[upd]]
+        best_y[rows[upd]] = call["y"][j[upd]]
+    assert np.isfinite(best_score).sum() > 0
+    # What `_pdhg_setup` makes of the device array: zeros where no
+    # candidate has a finite score.
+    best = calls[-1]["best"]
+    have = np.isfinite(best[:, :1])
+    assert np.array_equal(best[:, 0], best_score)
+    assert np.array_equal(np.where(have, best[:, 1:1 + solver.n], 0.0),
+                          best_z)
+    assert np.array_equal(np.where(have, best[:, 1 + solver.n:], 0.0),
+                          best_y)
+    d = solver.diagnostics
+    assert (d["n_pdhg"], d["n_fallback_exact"], d["pdhg_iters_max"]) == \
+        (118, 2, 20000)
+
+
+@pytest.mark.parametrize("case,q", [("nominal", 8), ("stressed", 24)])
+def test_candidate_statistics_match_host_formula(request, case, q):
+    """The summary each candidate call returns holds the statistics
+    `_ChunkArrays._stats` computes from the same call's `z` and row
+    values: violation counts exactly, unmet and utilization to 1e-12."""
+    if case == "nominal":
+        inst = request.getfixturevalue("inst")
+        dep = request.getfixturevalue("deploy")
+        batch = _batch(inst, 300)
+    else:
+        inst, dep = request.getfixturevalue("stressed")
+        batch = _batch(inst, 160)
+    solver, _, calls = _logged_solve(Stage2System(inst, dep), batch)
+    mine = [c for c in calls if c["Hq"].shape[1] == q]
+    assert mine
+    for call in mine:
+        Sb = call["z"].shape[0]
+        host = _ChunkArrays(Sb, solver.n_fam)
+        host._stats(np.arange(Sb), call["z"], call["rowsv"], solver)
+        summ = call["summary"]
+        assert summ.shape == (Sb, 4 + solver.n_fam)
+        assert np.array_equal(summ[:, 2], host.viols)
+        np.testing.assert_allclose(summ[:, 3], host.unmet, rtol=1e-12,
+                                   atol=0)
+        np.testing.assert_allclose(summ[:, 4:], host.util, rtol=1e-12,
+                                   atol=0)
 
 
 # -- metrics ------------------------------------------------------------

@@ -65,9 +65,12 @@ class _Tracked:
 
 
 def _tracked(program, log: list):
-    """`program` with each of its outputs wrapped in `_Tracked`."""
+    """`program` with each of its outputs wrapped in `_Tracked`; outputs
+    handed back to it as inputs (the risk kernel's device-resident warm
+    starts) go in unwrapped, so passing them reads nothing."""
     def run(*args):
-        out = program(*args)
+        out = program(*(a.value if isinstance(a, _Tracked) else a
+                        for a in args))
         if isinstance(out, tuple):
             return tuple(_Tracked(v, log) for v in out)
         return _Tracked(out, log)
@@ -167,6 +170,18 @@ def test_readback_bytes_count_what_numpy_read(traced, service):
     if service == "risk":
         assert diag["n_pdhg"] == 0      # only the candidate path read back
     assert diag["readback_bytes"] == sum(log)
+
+
+def test_risk_reads_back_one_summary_per_candidate_row(traced):
+    """The candidate path reads back each padded row's verdict, cost and
+    statistics, never its iterates: a change that reads `z`, `y`,
+    `rowsv` or `score` back again overshoots this bound."""
+    from repro.core.stage2 import Stage2System
+    r = traced["risk"]
+    assert r["n_pdhg"] == 0
+    n_fam = len(Stage2System.ROW_FAMILIES)
+    assert 0 < r["readback_bytes"] <= 8 * (4 + n_fam) \
+        * r["candidate_rows_padded"]
 
 
 def test_upload_and_row_counters(traced):
