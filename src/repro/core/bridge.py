@@ -62,7 +62,8 @@ def calibrate_from_dryrun(inst: Instance, dryrun_json: str,
                           arch_to_model: dict[str, int]) -> Instance:
     """Re-fit d_comp from compiled decode dry-runs: per-token HBM bytes per
     device / BW — the planner's bandwidth-bound decode roofline, measured on
-    the actual compiled program instead of a datasheet."""
+    the actual compiled program instead of a datasheet.  Only the per-token
+    bytes (`B_act`) are re-fit, never the resident `B`."""
     with open(dryrun_json) as f:
         rows = json.load(f)
     scale = {}
@@ -80,9 +81,12 @@ def calibrate_from_dryrun(inst: Instance, dryrun_json: str,
     tau_scale = np.ones(inst.J)
     for j, s in scale.items():
         tau_scale[j] = s
-    # d_comp = tau_i * B_j * nu_k / BW_k  -> fold the compiled-bytes ratio
-    # into an effective per-model multiplier on B_j.
-    inst.B = inst.B * tau_scale
+    # d_comp = tau_i * B_act_j * nu_k / BW_k  -> fold the compiled-bytes
+    # ratio into the bytes a token reads, B_act_j.  The resident weights B_j
+    # (memory, storage) are what they were.  d_comp models the weight
+    # stream, so a ratio that would read more than the resident weights
+    # stops at them.
+    inst.B_act = np.minimum(inst.B_act * tau_scale, inst.B)
     inst.__post_init__()
     return inst
 

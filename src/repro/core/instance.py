@@ -66,7 +66,7 @@ class Instance:
     theta: np.ndarray               # [I] KB/token storage footprint
 
     # --- models ---------------------------------------------------------
-    B: np.ndarray                   # [J] weight footprint GB (FP16)
+    B: np.ndarray                   # [J] resident weight footprint GB (FP16)
     beta: np.ndarray                # [J] KV-cache KB/token
     e_base: np.ndarray              # [I, J] FP16 base error rate
 
@@ -92,6 +92,10 @@ class Instance:
     phase1_beta: float = 0.8        # GH Phase-1 budget fraction
     tau: np.ndarray | None = None   # [I] task-specific overhead for d_comp
     kv_applicable: np.ndarray | None = None  # [J] bool; False for SSM-state models
+    # [J] weight GB read per token at FP16 (the active experts of a sparse
+    # model); None means B, a dense row.  Sets d_comp and alpha; every
+    # memory and storage term keeps the resident B.
+    B_act: np.ndarray | None = None
     # --- supply-side availability (core/faults.py) -----------------------
     # All three default to None, which means "the unbounded on-demand fleet
     # of the paper" — every solver/tensor path is bit-identical to the
@@ -109,12 +113,21 @@ class Instance:
             self.tau = np.ones(I)
         if self.kv_applicable is None:
             self.kv_applicable = np.ones(J, dtype=bool)
+        if self.B_act is None:
+            self.B_act = self.B
+        else:
+            self.B_act = np.asarray(self.B_act, float)
+            if (self.B_act.shape != np.shape(self.B)
+                    or not np.all((self.B_act > 0) & (self.B_act <= self.B))):
+                raise ValueError("B_act must hold one value per model with "
+                                 "0 < B_act <= B")
         self.r = self.h + self.f                                  # [I]
         # Effective weight footprint: nu shrinks bytes-per-weight (§3.1(4)).
         self.B_eff = self.B[:, None] * self.nu[None, :]            # [J, K]
         # Per-token compute delay at TP=1 (memory-bandwidth-bound decode
-        # roofline, d_comp = tau_i * B_j * nu_k / BW_k) — paper §5.1.
-        self.d_comp = (self.tau[:, None, None] * self.B[None, :, None]
+        # roofline, d_comp = tau_i * B_j * nu_k / BW_k) — paper §5.1, with
+        # the bytes a token reads (B_act) in place of B.
+        self.d_comp = (self.tau[:, None, None] * self.B_act[None, :, None]
                        * self.nu[None, None, :] / self.BW[None, None, :])  # [I,J,K]
         # Per-token inter-stage communication delay: activation bytes over
         # NVLink-class interconnect plus a fixed per-hop latency.
@@ -124,7 +137,8 @@ class Instance:
         # Per-token compute cost (GFLOP/token): ~2 FLOP per active parameter,
         # scaled by precision (paper: "model FLOPs scaled by tier precision").
         self.alpha = np.broadcast_to(
-            self.B[None, :, None] * self.nu[None, None, :], (I, J, K)).copy()
+            self.B_act[None, :, None] * self.nu[None, None, :],
+            (I, J, K)).copy()
         # KV residency weight (see README of core/): the paper's T_res is
         # "calibrated as the per-token decode duration"; we fold the arrival
         # rate into the calibration so that beta_j * sum_i r_i * T_res * x
@@ -187,8 +201,8 @@ class Instance:
             self.mem_ok = self.mem_ok & (
                 self.nm[None, None, :] <= self.avail_gpus[None, :, None])
         # Joint M1 feasibility per candidate: memory AND delay SLO.
-        feas = self.mem_ok[None, :, :, :] & (
-            self.D_cfg <= self.Delta[:, None, None, None])          # [I,J,K,C]
+        delay_ok = self.D_cfg <= self.Delta[:, None, None, None]    # [I,J,K,C]
+        feas = self.mem_ok[None, :, :, :] & delay_ok
         # Lexicographic argmin over (nm, delay, config index): first take the
         # minimal nm among feasible configs, then the minimal delay within
         # that nm level, then the first config index (np.argmax on a boolean
@@ -203,6 +217,10 @@ class Instance:
         first = tie & (d_masked == d_min[..., None])
         self.cfg_m1 = np.where(any_feas, first.argmax(axis=3), -1)  # [I,J,K]
         self.m1_nm = np.where(any_feas, nm_min, 0).astype(np.int64)
+        nm_delay = np.where(delay_ok, self.nm[None, None, None, :],
+                            big).min(axis=3)
+        self.m1_cells = int(any_feas.sum())
+        self.m1_mem_bound = int(np.sum(any_feas & (nm_min > nm_delay)))
         # No-M1 ablation always "selects" the globally cheapest config.
         self.cfg_min_nm = int(np.argmin(self.nm))
         # Error-SLO admissibility and Phase-1 coverage mask.

@@ -106,7 +106,9 @@ class Stage2System:
         self.n = nx + I
 
         # --- per-triple base factors (scenario value = base × factor) -----
-        bw_term = inst.B[tj] * inst.nu[tk] / inst.BW[tk]   # d_comp / tau
+        # Bytes read per token (B_act) set delay and load; the resident B
+        # only memory and storage.
+        bw_term = inst.B_act[tj] * inst.nu[tk] / inst.BW[tk]   # d_comp / tau
         r_t, f_t = inst.r[ti], inst.f[ti]
         nm_t, n_t, m_t = nm_p[tp], n_arr[cfg_p][tp], m_arr[cfg_p][tp]
         # (8f) applies only to KV-cache models (SSM-state models have no
@@ -115,7 +117,7 @@ class Stage2System:
         sel_kv = inst.kv_applicable[tj]
         self.kvA = (inst.beta[tj] / KB_PER_GB / nm_t * r_t
                     * f_t / T_CONV * bw_term)[sel_kv]
-        self.gA = inst.B[tj] * inst.nu[tk] * r_t / 1e3     # alpha · r (8g)
+        self.gA = inst.B_act[tj] * inst.nu[tk] * r_t / 1e3  # alpha · r (8g)
         self.sA = inst.theta[ti] / KB_PER_GB * r_t         # (8h) and c_x
         self.dA = bw_term * r_t / n_t                      # D_cfg tau-part
         self.dB = m_t * inst.d_comm[ti, tj, tk] * f_t      # D_cfg comm-part

@@ -142,6 +142,8 @@ class PlanResult:
     ``diagnostics`` is solver-specific but JSON-safe: AGH reports
     orderings evaluated, local-search moves applied, drains, fallback
     rescans, and warm-start provenance; MILP reports its status string.
+    Every solver's carries the instance's M1 counters ``m1_cells`` and
+    ``m1_mem_bound`` (`Instance._precompute_allocation_tensors`).
     """
     solver: str
     solution: Solution
@@ -263,5 +265,7 @@ def build_result(solver: str, inst: Instance, sol: Solution, wall_s: float,
             cost_breakdown=cost_terms(inst, sol),
             slack=slack_report(inst, sol, usage=usage), violations=viol,
             feasible=all(v <= 1e-4 for v in viol.values()),
-            wall_s=wall_s, cpu_s=cpu_s, diagnostics=diagnostics,
+            wall_s=wall_s, cpu_s=cpu_s,
+            diagnostics=dict(diagnostics, m1_cells=inst.m1_cells,
+                             m1_mem_bound=inst.m1_mem_bound),
             options=options.to_dict())

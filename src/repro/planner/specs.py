@@ -26,6 +26,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.core.catalog import ModelRow, with_model_rows
 from repro.core.instance import Instance, default_instance, random_instance
 from repro.core.trace import (diurnal_multipliers, multi_day_multipliers,
                               random_walk_lambdas)
@@ -125,11 +126,14 @@ class WorkloadSpec:
 
     ``family="paper"`` uses the Azure-trace-calibrated base population
     (§5.1); ``family="synthetic"`` draws a population of (I, J, K) types /
-    models / tiers with `random_instance`.  ``demand`` picks the temporal
-    process for `demand_path`: "flat" (constant), "diurnal" (busy-day
-    trace replica), "bursty" (volatile-day replica: deeper peaks, heavier
-    noise), "multi-day" (busy+volatile concatenation), or "random-walk"
-    (geometric, volatility ``sigma``).
+    models / tiers with `random_instance`.  ``models`` replaces the
+    family's model catalog with the given `repro.core.catalog.ModelRow`s
+    (e.g. sparse-expert rows from `mla_moe_row`, which state their active
+    bytes), through `repro.core.catalog.with_model_rows`.  ``demand``
+    picks the temporal process for `demand_path`: "flat" (constant),
+    "diurnal" (busy-day trace replica), "bursty" (volatile-day replica:
+    deeper peaks, heavier noise), "multi-day" (busy+volatile
+    concatenation), or "random-walk" (geometric, volatility ``sigma``).
     """
     family: str = "paper"
     I: int = 6
@@ -140,6 +144,7 @@ class WorkloadSpec:
     n_windows: int = 288
     days: tuple[str, ...] = ("busy", "volatile")
     sigma: float = 0.03
+    models: tuple[ModelRow, ...] | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,6 +183,8 @@ class ScenarioSpec:
         else:
             raise ValueError(f"unknown workload family {w.family!r} "
                              f"(expected 'paper' or 'synthetic')")
+        if w.models is not None:
+            inst = with_model_rows(inst, w.models)
         inst = self.fleet.apply(inst)
         if s.stress is not None:
             inst = inst.stressed(s.stress)
