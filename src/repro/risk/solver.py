@@ -11,10 +11,10 @@ cheapest first:
    evaluation family).  An *anchor* is an optimal basis harvested from
    one exact solve: (active rows, basic columns, nonbasic-at-upper-bound
    columns), completed to a square basis through pivoted Gram-Schmidt
-   when the vertex is degenerate.  For a batch of scenarios the solver
-   proposes the candidate vertex/dual of the most promising anchor
-   (first pass: nearest hit-centroid in perturbation space; retries:
-   most-hit untried anchor).  The k x k active systems
+   when the vertex is degenerate.  Each unresolved scenario tries its
+   untried anchors in descending hits (ties by anchor index), so within
+   a chunk every scenario left after a pass picks the same next anchor
+   and each pass makes one candidate call.  The k x k active systems
    B(s) z_B = rhs_eff(s)  and  B(s)^T y = -c_B(s)  are solved EXACTLY
    in closed form by exploiting how scenarios perturb the constraint
    matrix: equality rows are scenario-constant, kv/compute/storage rows
@@ -374,28 +374,12 @@ class _Anchor:
     act: np.ndarray            # active rows
     bas: np.ndarray            # basic columns (sorted; keying only)
     nb_ub: np.ndarray          # nonbasic columns at upper bound
-    feat: np.ndarray           # perturbation-space features of the source
     pack: tuple                # padded device tensors for _candidate_kernel
     hits: int = 0
-    feat_sum: np.ndarray = dataclasses.field(default=None)  # type: ignore[assignment]
-
-    def __post_init__(self) -> None:
-        if self.feat_sum is None:
-            self.feat_sum = np.zeros_like(self.feat)
 
     @property
     def key(self) -> tuple:
         return (tuple(self.act.tolist()), tuple(self.bas.tolist()))
-
-    @property
-    def centroid(self) -> np.ndarray:
-        """Running mean of the features this anchor has solved.
-
-        Far more predictive than the harvest scenario's own features —
-        the source sits at the EDGE of its basis cell, the centroid near
-        the middle.  Falls back to the source until the first hit.
-        """
-        return self.feat_sum / self.hits if self.hits else self.feat
 
 
 class BatchedStage2Solver(ExactChunkSolver):
@@ -416,16 +400,13 @@ class BatchedStage2Solver(ExactChunkSolver):
         self.max_anchors = max_anchors
         self.pdhg_max_iter = pdhg_max_iter
         self.pdhg_check = pdhg_check
-        inst = system.inst
-        base_e = inst.e_base.mean(axis=1)
-        self._feat_base = np.concatenate([inst.tau, inst.lam, base_e])
         self.anchors: list[_Anchor] = []
         self._anchor_keys: set[tuple] = set()
         self.diagnostics = {
             "n_anchor0": 0, "n_harvest_exact": 0, "n_pdhg": 0,
             "n_fallback_exact": 0, "pdhg_iters_max": 0, "n_scenarios": 0,
-            "upload_bytes": 0, "readback_bytes": 0, "candidate_rows": 0,
-            "candidate_rows_padded": 0,
+            "upload_bytes": 0, "readback_bytes": 0, "candidate_calls": 0,
+            "candidate_rows": 0, "candidate_rows_padded": 0,
         }
         # Static device-side pattern tensors, shared by every kernel call.
         f64 = jnp.float64
@@ -454,8 +435,7 @@ class BatchedStage2Solver(ExactChunkSolver):
         self._d_rhs_safe = jnp.asarray(np.maximum(self.rhs0, 1e-12),
                                        dtype=f64)
 
-    def _harvest_anchor(self, res, vals: np.ndarray, feat: np.ndarray
-                        ) -> bool:
+    def _harvest_anchor(self, res, vals: np.ndarray) -> bool:
         """Extract an optimal basis from a linprog result; True if new."""
         n, nx, m_ub, I = self.n, self.nx, self.m_ub, self.I
         z = res.x
@@ -504,7 +484,7 @@ class BatchedStage2Solver(ExactChunkSolver):
             return False
         self._anchor_keys.add(key)
         self.anchors.append(
-            _Anchor(act=act, bas=bas, nb_ub=nb_ub, feat=feat, pack=pack))
+            _Anchor(act=act, bas=bas, nb_ub=nb_ub, pack=pack))
         return True
 
     def _pack(self, act: np.ndarray, bas: np.ndarray, nb_ub: np.ndarray,
@@ -641,26 +621,12 @@ class BatchedStage2Solver(ExactChunkSolver):
         self.diagnostics["readback_bytes"] += host.nbytes
         return host
 
-    # -- scenario features (anchor ordering only; no correctness role) --
-    def _features(self, batch: ScenarioBatch) -> np.ndarray:
-        inst = self.system.inst
-        S = batch.S
-        tau = (np.broadcast_to(inst.tau, (S, inst.I)) if batch.tau is None
-               else batch.tau)
-        lam = (np.broadcast_to(inst.lam, (S, inst.I)) if batch.lam is None
-               else batch.lam)
-        eb = (np.broadcast_to(inst.e_base.mean(axis=1), (S, inst.I))
-              if batch.e_base is None else batch.e_base.mean(axis=2))
-        feats = np.concatenate([tau, lam, eb], axis=1)
-        return feats / np.maximum(self._feat_base[None, :], 1e-12)
-
     # -- the batched solve ----------------------------------------------
     def solve_scenarios(self, batch: ScenarioBatch) -> _ChunkArrays:
         system = self.system
         S = batch.S
         with span("stage2.coefficients"):
             vals, c = system.coefficient_batch(batch)
-            feats = self._features(batch)
         out = _ChunkArrays(S, self.n_fam)
         diag = self.diagnostics
         diag["n_scenarios"] += S
@@ -670,8 +636,7 @@ class BatchedStage2Solver(ExactChunkSolver):
                 v0, c0 = system.coefficient_batch(ScenarioBatch(S=1))
             res0 = self._exact(v0[0], c0[0])
             with span("stage2.record"):
-                self._harvest_anchor(res0, v0[0],
-                                     np.ones_like(self._feat_base))
+                self._harvest_anchor(res0, v0[0])
 
         # One chunk-wide device residency (padded to a bucket so chunk
         # length doesn't multiply kernel compiles); per-group rows are
@@ -684,7 +649,6 @@ class BatchedStage2Solver(ExactChunkSolver):
             c_p[:S] = c
             d_vals_all = self._upload(vals_p, jnp.float64)
             d_c_all = self._upload(c_p, jnp.float64)
-        feat_sq = np.sum(feats * feats, axis=1)
 
         unresolved = np.arange(S)
         tried = np.zeros((S, 0), dtype=bool)
@@ -711,24 +675,13 @@ class BatchedStage2Solver(ExactChunkSolver):
                             [tried, np.zeros((S, A - tried.shape[1]), bool)],
                             axis=1)
                     # Anchor ordering (heuristic only — never affects
-                    # correctness): first pass goes to the nearest
-                    # hit-centroid, retries walk the untried anchors by
-                    # hit frequency.
-                    afeat = np.stack([a.centroid for a in self.anchors])
-                    hits = np.array([a.hits for a in self.anchors],
-                                    dtype=float)
+                    # correctness): the most-hit untried anchor, the
+                    # lowest index among equals (argmax takes the first).
+                    hits = np.array([a.hits for a in self.anchors])
                     t_u = tried[unresolved]
-                    fu = feats[unresolved]
-                    dist = (feat_sq[unresolved, None]
-                            + np.sum(afeat * afeat, axis=1)[None, :]
-                            - 2.0 * (fu @ afeat.T))
-                    dist[t_u] = np.inf
-                    hit_score = np.where(t_u, -np.inf, hits[None, :])
-                    first = ~t_u.any(axis=1)
-                    pick = np.where(first, np.argmin(dist, axis=1),
-                                    np.argmax(hit_score, axis=1))
-                    exhausted = ~np.isfinite(
-                        dist[np.arange(unresolved.size), pick])
+                    pick = np.argmax(np.where(t_u, -1, hits[None, :]),
+                                     axis=1)
+                    exhausted = t_u.all(axis=1)
                     exhausted_idx = unresolved[exhausted]
                     live = unresolved[~exhausted]
                     pick = pick[~exhausted]
@@ -742,6 +695,7 @@ class BatchedStage2Solver(ExactChunkSolver):
                 # scenarios actually trying this anchor.
                 Sg = grp.size
                 Sb = _bucket(Sg)
+                diag["candidate_calls"] += 1
                 diag["candidate_rows"] += Sg
                 diag["candidate_rows_padded"] += Sb
                 with span("stage2.candidate.dispatch"):
@@ -772,7 +726,6 @@ class BatchedStage2Solver(ExactChunkSolver):
                     hit = grp[ok_np]
                     if hit.size:
                         anchor.hits += int(hit.size)
-                        anchor.feat_sum += feats[hit].sum(axis=0)
                         diag["n_anchor0"] += int(hit.size)
                         sh = summ[ok_np]
                         out.costs[hit] = sh[:, 1]
@@ -791,7 +744,7 @@ class BatchedStage2Solver(ExactChunkSolver):
                     diag["n_harvest_exact"] += 1
                     with span("stage2.record"):
                         self._record_exact(s, vals[s], c[s], res, out)
-                        self._harvest_anchor(res, vals[s], feats[s])
+                        self._harvest_anchor(res, vals[s])
                     unresolved = np.concatenate(
                         [leftovers, exhausted_idx[1:]])
                     continue

@@ -85,13 +85,21 @@ def test_pdhg_matches_oracle_stressed_wide_bases(stressed):
     assert solver.diagnostics["n_anchor0"] > 0
 
 
-def _logged_solve(system, batch, **kw):
-    """Solve `batch`, keeping host copies of every candidate call's
-    inputs (by name) and outputs."""
+def _logged_solve(system, batch, solver=None, **kw):
+    """Solve `batch` (with `solver` when given, so its anchors carry
+    over), keeping host copies of every candidate call's inputs (by name)
+    and outputs, the anchor it tried and the assignment pass it made."""
     from repro.risk import solver as solver_mod
     real = solver_mod._candidate_kernel
+    real_span = solver_mod.span
     params = list(inspect.signature(real).parameters)
     calls = []
+    passes = 0
+
+    def counted(name):
+        nonlocal passes
+        passes += name == "stage2.assign"
+        return real_span(name)
 
     def logged(*args):
         named = {k: np.asarray(a) for k, a in zip(params, args)
@@ -100,12 +108,17 @@ def _logged_solve(system, batch, **kw):
         call = dict(named, **{k: np.asarray(v) for k, v in zip(
             ("score", "summary", "best", "z", "y", "rowsv"), out)})
         call["inv"] = call["idx"][call["score"].size:]
+        call["anchor"] = next(j for j, a in enumerate(solver.anchors)
+                              if a.pack[-1] is args[-1])
+        call["pass"] = passes
         calls.append(call)
         return out
 
-    solver = BatchedStage2Solver(system, **kw)
+    if solver is None:
+        solver = BatchedStage2Solver(system, **kw)
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(solver_mod, "_candidate_kernel", logged)
+        mp.setattr(solver_mod, "span", counted)
         out = solver.solve_scenarios(batch)
     return solver, out, calls
 
@@ -170,6 +183,64 @@ def test_warm_starts_are_each_miss_best_candidate(forced):
     d = solver.diagnostics
     assert (d["n_pdhg"], d["n_fallback_exact"], d["pdhg_iters_max"]) == \
         (118, 2, 20000)
+
+
+@pytest.fixture(scope="module")
+def second_chunk():
+    """Two chunks on the unstressed (20,20,20) instance's GH plan: the
+    first harvests the full anchor set (`max_anchors`, 32), the second
+    starts with it.  Returns the solver, each anchor's hits and the
+    solver's diagnostics as the second chunk starts, and each chunk's
+    logged calls."""
+    big = random_instance(20, 20, 20, seed=42)
+    system = Stage2System(big, gh(big))
+    solver, _, calls1 = _logged_solve(system, _batch(big, 600, seed=21))
+    assert len(solver.anchors) == solver.max_anchors
+    hits0 = [a.hits for a in solver.anchors]
+    diag0 = dict(solver.diagnostics)
+    _, _, calls2 = _logged_solve(system, _batch(big, 300, seed=22),
+                                 solver=solver)
+    return solver, hits0, diag0, calls1, calls2
+
+
+def test_full_anchor_set_makes_one_call_per_pass(second_chunk):
+    """Every scenario left after a pass has tried the same anchors, so
+    each assignment pass of the second chunk makes exactly one candidate
+    call, with the whole chunk first and then exactly the previous
+    call's misses, until every scenario is settled on an anchor."""
+    solver, _, diag0, _, calls = second_chunk
+    assert [c["pass"] for c in calls] == list(range(1, len(calls) + 1))
+    group = np.arange(300)
+    for call in calls:
+        rows = np.where(call["inv"] < call["score"].size)[0]
+        assert np.array_equal(rows, group)
+        group = rows[call["summary"][call["inv"][rows], 0] == 0.0]
+    assert group.size == 0
+    d = solver.diagnostics
+    assert d["n_anchor0"] - diag0["n_anchor0"] == 300
+    assert d["n_harvest_exact"] == diag0["n_harvest_exact"]
+
+
+def test_anchors_are_tried_in_descending_hits(second_chunk):
+    """From the first pass, the untried anchor with the most hits comes
+    next, the lower index among equals: an anchor's hits change only
+    once it has been tried, so the second chunk walks the order its
+    hits had when the chunk started."""
+    _, hits0, _, _, calls = second_chunk
+    order = sorted(range(len(hits0)), key=lambda j: (-hits0[j], j))
+    assert len(set(hits0)) < len(hits0)       # ties occur
+    assert [c["anchor"] for c in calls] == order[:len(calls)]
+
+
+def test_candidate_calls_counts_kernel_calls(second_chunk):
+    """`candidate_calls` counts `_candidate_kernel` calls, and
+    `candidate_rows` the chunk rows they tried."""
+    solver, _, diag0, calls1, calls2 = second_chunk
+    assert diag0["candidate_calls"] == len(calls1)
+    d = solver.diagnostics
+    assert d["candidate_calls"] == len(calls1) + len(calls2)
+    rows2 = sum(int((c["inv"] < c["score"].size).sum()) for c in calls2)
+    assert d["candidate_rows"] - diag0["candidate_rows"] == rows2
 
 
 @pytest.mark.parametrize("case,q", [("nominal", 8), ("stressed", 24)])
