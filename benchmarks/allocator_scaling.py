@@ -11,17 +11,14 @@ committed baseline:
 * ``agh``            — the incremental engine (default);
 * ``agh+rescan``     — dirty-source tracking disabled (PR-3-style);
 * ``agh+reference``  — the PR-1/PR-2 first-improvement probe loop;
+  these two are `core.agh.agh` test-oracle modes, called directly;
 * ``agh+warm``       — `PlanSession.replan` on a ±15% drifted demand
   vector, seeded from the undrifted incumbent, next to the cold AGH
   solve of the same drifted instance (``cold_*`` fields + ``speedup``);
-* ``agh+workersN``   — `--workers-sweep`: the multi-start process-pool
-  fan-out at widths 1/2/4/8 (numpy engine only);
 * ``agh+xla``        — `--engine xla`: the jitted lane-batched tier.
   Every xla cell is solved twice: the first run pays jit tracing and is
   reported as ``compile_s`` (first minus second wall), the second run's
   steady-state timing is what the row and the regression gate see;
-* ``agh+xla+bwN``    — `--bw-curve`: the orderings-batch-width scaling
-  curve (device lanes per call capped at N = 1/2/4/8);
 * flat ``GH_before_us`` / ``AGH_before_us`` — the frozen scalar seed
   path, kept at sizes where it finishes in seconds.
 
@@ -39,7 +36,7 @@ from repro.core._scalar_ref import agh_scalar, gh_scalar
 from repro.core.solution import objective
 from repro.planner import PlanOptions, PlanResult, PlanSession, plan
 
-from .common import Timer, emit
+from .common import Timer, agh_mode, emit
 
 SIZES = [(6, 6, 10), (10, 10, 10), (15, 15, 10), (20, 20, 20), (30, 30, 20),
          (40, 40, 30), (60, 60, 40)]
@@ -51,8 +48,6 @@ SCALAR_AGH_MAX = 10 * 10 * 10   # scalar AGH above this takes minutes
 SCALAR_GH_MAX = 30 * 30 * 20    # scalar GH above this takes tens of seconds
 REF_AGH_MAX = 100 * 80 * 40     # reference-mode AGH above this: minutes
 DRIFT_PM = 0.15                 # warm-replan demo: ±15% per-type demand
-WORKER_WIDTHS = (1, 2, 4, 8)    # --workers-sweep fan-out widths
-BW_WIDTHS = (1, 2, 4, 8)        # --bw-curve xla lane-batch widths
 
 
 def _cell(row: dict, size: str, key: str, inst,
@@ -76,22 +71,26 @@ def _cell(row: dict, size: str, key: str, inst,
     return res
 
 
-def _run_xla_row(row: dict, size: str, inst, bw_curve: bool) -> None:
+def _oracle_cell(row: dict, size: str, key: str, inst,
+                 local_search: str) -> None:
+    """One `agh` test-oracle local-search mode -> summary + CSV line."""
+    res = agh_mode(inst, local_search)
+    row[key] = res.summary()
+    emit(f"allocator_scaling.{size}.{key}", res.wall_s * 1e6,
+         f"obj={res.objective:.2f}")
+
+
+def _run_xla_row(row: dict, size: str, inst) -> None:
     res = _cell(row, size, "agh+xla", inst, PlanOptions(engine="xla"))
     emit(f"allocator_scaling.{size}.agh+xla.compile",
          row["agh+xla"]["compile_s"] * 1e6,
          f"steady_s={res.wall_s:.3f}")
-    if bw_curve:
-        for bw in BW_WIDTHS:
-            _cell(row, size, f"agh+xla+bw{bw}", inst,
-                  PlanOptions(engine="xla", batch_width=bw))
 
 
 def run(sizes=SIZES, scalar_agh_max: int = SCALAR_AGH_MAX,
         scalar_gh_max: int = SCALAR_GH_MAX,
         ref_agh_max: int = REF_AGH_MAX, warm_demo: bool = True,
-        engine: str = "numpy", workers_sweep: bool = False,
-        bw_curve: bool = False) -> list[dict]:
+        engine: str = "numpy") -> list[dict]:
     rows = []
     for (I, J, K) in sizes:
         inst = random_instance(I, J, K, seed=42)
@@ -101,7 +100,7 @@ def run(sizes=SIZES, scalar_agh_max: int = SCALAR_AGH_MAX,
         if engine == "xla":
             # The xla tier rides its own rows (same sizes, engine-keyed
             # so the gate never diffs them against numpy timings).
-            _run_xla_row(row, size, inst, bw_curve)
+            _run_xla_row(row, size, inst)
             rows.append(row)
             continue
 
@@ -120,32 +119,20 @@ def run(sizes=SIZES, scalar_agh_max: int = SCALAR_AGH_MAX,
             emit(f"allocator_scaling.{size}.AGH.before", t.us,
                  f"obj={objective(inst, a_ref):.2f}")
         if I * J * K <= ref_agh_max:
-            _cell(row, size, "agh+reference", inst,
-                  PlanOptions(local_search="reference"))
-        _cell(row, size, "agh+rescan", inst,
-              PlanOptions(local_search="batched-rescan"))
+            _oracle_cell(row, size, "agh+reference", inst, "reference")
+        _oracle_cell(row, size, "agh+rescan", inst, "batched-rescan")
         agh_res = _cell(row, size, "agh", inst)
-
-        if workers_sweep:
-            # Multi-start fan-out scaling: all orderings, no early stop
-            # (the pool protocol), at fixed pool widths.
-            for w in WORKER_WIDTHS:
-                _cell(row, size, f"agh+workers{w}", inst,
-                      PlanOptions(workers=w))
 
         if warm_demo:
             # Warm-started replanning (ISSUE 5 acceptance): drift every
             # type's demand by ±15%, solve cold, then replan warm from the
             # undrifted incumbent.  The session is seeded with the `agh`
-            # row's result (no duplicate cold solve); the drifted cold
-            # comparator and the replan both run the sequential driver
-            # (workers=0) so the comparison is machine-independent.
+            # row's result (no duplicate cold solve).
             drift = np.random.default_rng(7).uniform(
                 1.0 - DRIFT_PM, 1.0 + DRIFT_PM, inst.I)
             drifted = inst.with_lam(inst.lam * drift)
-            cold = plan("agh", instance=drifted,
-                        options=PlanOptions(workers=0))
-            ses = PlanSession(options=PlanOptions(workers=0))
+            cold = plan("agh", instance=drifted)
+            ses = PlanSession()
             ses.seed(inst, agh_res)
             warm = ses.replan(instance=drifted)
             row["agh+warm"] = {
@@ -172,11 +159,6 @@ if __name__ == "__main__":
     ap.add_argument("--engine", default="numpy", choices=("numpy", "xla"),
                     help="allocator engine for the agh rows (xla adds "
                          "compile-vs-steady split; needs jax)")
-    ap.add_argument("--workers-sweep", action="store_true",
-                    help="add agh+workersN rows at widths 1/2/4/8")
-    ap.add_argument("--bw-curve", action="store_true",
-                    help="with --engine xla: add agh+xla+bwN rows "
-                         "(orderings-batch-width scaling curve)")
     ap.add_argument("--trajectory-out", default=None, metavar="PATH",
                     help="append this run's rows to the trajectory "
                          "artifact (e.g. BENCH_allocator.json)")
@@ -186,8 +168,7 @@ if __name__ == "__main__":
     out_rows = run(sizes=(QUICK_SIZES if args.quick else
                           (SIZES_XL if args.xl else SIZES)),
                    scalar_agh_max=args.scalar_agh_max,
-                   engine=args.engine, workers_sweep=args.workers_sweep,
-                   bw_curve=args.bw_curve)
+                   engine=args.engine)
     if args.trajectory_out:
         from .trajectory import append
         append(args.trajectory_out, out_rows,

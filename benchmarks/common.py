@@ -50,6 +50,20 @@ def emit(name: str, us_per_call: float, derived: str) -> None:
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
 
 
+def agh_mode(inst, local_search: str):
+    """`core.agh.agh` under one of its test-oracle local-search modes
+    ("reference", "batched-rescan"), which the planner facade does not
+    offer, as the `PlanResult` a facade solve would give."""
+    from repro.core.agh import agh
+    from repro.planner import PlanOptions
+    from repro.planner.api import build_result
+    stats: dict = {}
+    t0, c0 = time.perf_counter(), time.process_time()
+    sol = agh(inst, local_search=local_search, stats=stats)
+    wall, cpu = time.perf_counter() - t0, time.process_time() - c0
+    return build_result("agh", inst, sol, wall, cpu, stats, PlanOptions())
+
+
 class Timer:
     def __enter__(self):
         self.t0 = time.perf_counter()

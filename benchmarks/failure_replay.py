@@ -59,7 +59,7 @@ def _build_case(I: int, J: int, K: int, T: int, seed: int = 42):
     solve of the unfaulted instance (the exact-gated anchor)."""
     inst = random_instance(I, J, K, seed=seed)
     inst = dataclasses.replace(inst, zeta=np.full(I, ZETA))
-    opts = PlanOptions(workers=0)
+    opts = PlanOptions()
     cold0 = plan("agh", instance=inst, options=opts)
     y_tier = cold0.solution.y.sum(axis=0)
     nominal = np.ceil(CAP_HEADROOM * y_tier) + 4

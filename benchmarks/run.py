@@ -21,9 +21,6 @@ def main(argv=None) -> int:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma-separated subset: table2,table3,...")
-    ap.add_argument("--workers", type=int, default=None,
-                    help="shared process-pool width for the sections that "
-                         "fan out (table2's scenario x method grid)")
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also dump every section's row dicts as one JSON "
                          "file (CI uploads it as a workflow artifact)")
@@ -41,8 +38,7 @@ def main(argv=None) -> int:
                    table3, table4, table5, table6)
 
     sections = {
-        "table2": lambda: table2.run(S=S, include_dm=False,
-                                     workers=args.workers),
+        "table2": lambda: table2.run(S=S, include_dm=False),
         "table3": lambda: table3.run(),
         "table4": lambda: table4.run(trials=trials, n_windows=windows,
                                      dm_limit=120.0 if not args.full else 600.0,

@@ -81,7 +81,7 @@ def run(sizes=SIZES, horizon_s: float = HORIZON_S,
         for mode in MODES:
             # Fresh session per mode: serve() advances the session in
             # place (the incumbent after a run is the last replan's).
-            sess = PlanSession(options=PlanOptions(workers=0))
+            sess = PlanSession(options=PlanOptions())
             with Timer() as t_plan:
                 res = sess.plan(instance=with_queueing_margin(inst, RHO_MAX))
             sr = serve(res, instance=inst, session=sess, traffic=traffic,

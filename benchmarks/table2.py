@@ -8,14 +8,6 @@ planner registry, so a newly registered solver shows up as a new column
 without touching this file.
 Metrics: Stage-1 cost, expected cost over S perturbed scenarios, SLO
 violation rate (>1% unserved per (scenario, type)).
-
-With ``workers`` (``benchmarks.run --workers``), the 5 scenarios x N
-methods cells are batched through ONE shared process pool — each cell
-(plan + S-scenario Stage-2 evaluation) is independent, so the grid
-parallelizes embarrassingly; results are gathered and emitted in the
-canonical scenario/method order, so the output is identical to the
-sequential path's.  Inside a pooled cell the Stage-2 ``workers=``
-fan-out stays off (the pool already owns the cores).
 """
 from __future__ import annotations
 
@@ -37,12 +29,10 @@ SCENARIOS = {
 METHODS = ("gh", "agh", "lpr", "dvr", "hf")
 
 
-def _run_cell(args: tuple) -> tuple[dict, float]:
+def _run_cell(sname: str, inst_kw: dict, mname: str, S: int,
+              u_cap: np.ndarray, dm_limit: float) -> tuple[dict, float]:
     """One (scenario, method) cell: plan on the forecast instance through
-    the registry facade, then the frozen-deployment Stage-2 evaluation.
-    Module-level and driven by picklable primitives so a process pool can
-    run it."""
-    sname, inst_kw, mname, S, u_cap, dm_limit = args
+    the registry facade, then the frozen-deployment Stage-2 evaluation."""
     inst = default_instance(seed=0, **inst_kw)
     # dm_limit caps the exact solver only; the other backends keep their
     # own defaults (LPR: 120 s) so --dm-limit never changes the baselines.
@@ -58,36 +48,17 @@ def _run_cell(args: tuple) -> tuple[dict, float]:
 
 
 def run(S: int = 100, include_dm: bool = False, dm_limit: float = 180.0,
-        u_cap: float = 1.0, workers: int | None = None) -> list[dict]:
+        u_cap: float = 1.0) -> list[dict]:
     cap = np.full(6, u_cap)
     methods = list(METHODS) + (["milp"] if include_dm else [])
-    cells = [(sname, kw, mname, S, cap, dm_limit)
-             for sname, kw in SCENARIOS.items() for mname in methods]
-    import multiprocessing as mp
-
-    from repro.runtime import backend_initialized
-    # A process that may hold the chip never forks: the inline path gives
-    # the same rows.
-    if (workers and workers > 1 and "fork" in mp.get_all_start_methods()
-            and not backend_initialized()):
-        import concurrent.futures as cf
-        from concurrent.futures.process import BrokenProcessPool
-        try:
-            ctx = mp.get_context("fork")
-            with cf.ProcessPoolExecutor(max_workers=workers,
-                                        mp_context=ctx) as ex:
-                results = list(ex.map(_run_cell, cells))
-        except (OSError, BrokenProcessPool):
-            # pool-infrastructure failure only; cell errors propagate
-            results = [_run_cell(c) for c in cells]
-    else:
-        results = [_run_cell(c) for c in cells]
     rows = []
-    for (sname, _, mname, *_), (row, us) in zip(cells, results):
-        rows.append(row)
-        emit(f"table2.{sname}.{mname}", us,
-             f"stage1=${row['stage1']};cost=${row['cost']};"
-             f"viol={row['viol_pct']}%")
+    for sname, kw in SCENARIOS.items():
+        for mname in methods:
+            row, us = _run_cell(sname, kw, mname, S, cap, dm_limit)
+            rows.append(row)
+            emit(f"table2.{sname}.{mname}", us,
+                 f"stage1=${row['stage1']};cost=${row['cost']};"
+                 f"viol={row['viol_pct']}%")
     return rows
 
 
@@ -96,8 +67,5 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--S", type=int, default=500)
     ap.add_argument("--dm", action="store_true")
-    ap.add_argument("--workers", type=int, default=None,
-                    help="fan the scenario x method grid over one shared "
-                         "process pool")
     args = ap.parse_args()
-    run(S=args.S, include_dm=args.dm, workers=args.workers)
+    run(S=args.S, include_dm=args.dm)

@@ -28,7 +28,7 @@ from repro.core import random_instance
 from repro.core._scalar_ref import gh_scalar
 from repro.planner import PlanOptions, plan
 
-from .common import Timer, emit
+from .common import Timer, agh_mode, emit
 
 SIZES = [(4, 4, 5), (6, 6, 10), (10, 10, 10), (15, 15, 10), (20, 20, 20)]
 SIZES_EXT = SIZES + [(30, 30, 20), (40, 40, 30), (60, 60, 40), (100, 80, 40),
@@ -52,9 +52,7 @@ def run(dm_limit: float = 600.0, dm_max_size: int = DM_MAX_SIZE,
         a = plan("agh", instance=inst)
         row["agh"] = a.summary()
         if include_before and I * J * K <= REF_AGH_MAX:
-            row["agh+reference"] = plan(
-                "agh", instance=inst,
-                options=PlanOptions(local_search="reference")).summary()
+            row["agh+reference"] = agh_mode(inst, "reference").summary()
         if I * J * K <= dm_max_size:
             d = plan("milp", instance=inst,
                      options=PlanOptions(time_limit=dm_limit))

@@ -29,16 +29,15 @@ a worse objective on the equivalence suite.
 (PR-1/PR-2 behavior), kept bit-identical to `_scalar_ref.agh_scalar` by
 tests/test_vectorized_equivalence.py.
 
-Multi-start fans out over a process pool when `workers` is given (auto for
-large instances): Phase 1 is ordering-independent, so its snapshot and the
-precomputed `Instance` tensors are shared with forked workers, and the
-reduction applies the sequential driver's strict-improvement rule in
-ordering-index order — the selected solution is independent of worker
-count and scheduling.
+Multi-start has one protocol, run in this process: Phase 1 is
+ordering-independent, so it runs once and every ordering starts from its
+snapshot; orderings are evaluated in list order, a result replaces the
+incumbent only when strictly better (by 1e-9), and the loop stops after
+`patience` consecutive non-improving orderings.  The xla tier's lane
+waves (`core/xla/engine.py`) replay this same rule.
 """
 from __future__ import annotations
 
-import os
 import time
 
 import numpy as np
@@ -575,11 +574,8 @@ def _assert_state_consistent(st: State) -> None:
 
 
 # ---------------------------------------------------------------------------
-# AGH driver (sequential early-stop or deterministic parallel fan-out)
+# AGH driver (sequential early-stop multi-start)
 # ---------------------------------------------------------------------------
-
-_PARALLEL_MIN_N = 24000     # auto fan-out only beyond (20,20,20)-class sizes
-
 
 def _run_ordering(inst: Instance, order: np.ndarray, p1_snap: tuple, L: int,
                   batched: bool, ranked: list[np.ndarray] | None,
@@ -675,92 +671,9 @@ def agh_repair(inst: Instance, incumbent: Solution, L: int = 1,
     return best
 
 
-# Fork-shared work description for the multi-start pool: set in the parent
-# immediately before the pool is created, inherited copy-on-write by the
-# forked workers (no per-task pickling of the Instance tensors).
-_FANOUT: dict = {}
-
-
-def _fanout_worker(idx: int):
-    inst = _FANOUT["inst"]
-    st = _run_ordering(inst, _FANOUT["orders"][idx],
-                       _FANOUT["p1"], _FANOUT["L"], _FANOUT["batched"],
-                       _FANOUT["ranked"], _FANOUT["validate"],
-                       _FANOUT["incremental"])
-    # Materialize through the one shared materializer so the parallel and
-    # sequential paths can never drift apart.
-    return (idx, state_objective(st), solution_from_state(inst, st))
-
-
-def _multi_start_parallel(inst: Instance, orders: list[np.ndarray],
-                          p1_snap: tuple, L: int, batched: bool,
-                          ranked: list[np.ndarray] | None, validate: bool,
-                          workers: int, incremental: bool = True):
-    """Evaluate every ordering (no early stop) and reduce deterministically.
-
-    The reduction scans results in ordering-index order with the sequential
-    driver's strict-improvement rule, so the returned solution is identical
-    for any worker count — and never worse than the early-stop sequential
-    protocol, which evaluates a prefix of the same orderings.  The last
-    value is True when a pool was asked for but the orderings ran inline."""
-    import multiprocessing as mp
-
-    from ..runtime import backend_initialized
-    asked = workers > 1
-    if asked and (mp.current_process().daemon
-                  or "fork" not in mp.get_all_start_methods()
-                  or backend_initialized()):
-        # Pool unavailable here, or this process may hold the chip and
-        # must not fork: same protocol inline.
-        workers = 1
-    _FANOUT.update(inst=inst, orders=orders, p1=p1_snap, L=L,
-                   batched=batched, ranked=ranked, validate=validate,
-                   incremental=incremental)
-    try:
-        if workers > 1:
-            import concurrent.futures as cf
-            from concurrent.futures.process import BrokenProcessPool
-            ctx = mp.get_context("fork")
-            try:
-                with cf.ProcessPoolExecutor(max_workers=workers,
-                                            mp_context=ctx) as ex:
-                    results = list(ex.map(_fanout_worker,
-                                          range(len(orders))))
-            except (OSError, BrokenProcessPool):
-                # Pool-infrastructure failure only (sandboxed spawn, killed
-                # worker): same protocol inline — the deterministic
-                # reduction makes the results identical.  Worker-side
-                # algorithm errors propagate unchanged.
-                results = [_fanout_worker(i) for i in range(len(orders))]
-                workers = 1
-        else:
-            results = [_fanout_worker(i) for i in range(len(orders))]
-    finally:
-        _FANOUT.clear()
-    results.sort(key=lambda r: r[0])
-    best, best_obj, best_idx = None, np.inf, -1
-    for idx, obj, sol in results:
-        if obj < best_obj - 1e-9:
-            best, best_obj, best_idx = sol, obj, idx
-    return best, best_obj, best_idx, asked and workers == 1
-
-
-def _auto_workers(inst: Instance, n_orders: int) -> int:
-    """Fan out only where it wins: large instances on boxes with enough
-    cores.  On <= 2 cores the pool's fork/IPC overhead plus the loss of
-    early stopping (the parallel protocol evaluates every ordering) beats
-    the speedup, measured end to end — so auto mode stays sequential
-    there and `workers=` remains an explicit opt-in."""
-    if inst.I * inst.J * inst.K < _PARALLEL_MIN_N:
-        return 0
-    cpus = os.cpu_count() or 1
-    return 0 if cpus < 4 else min(cpus, n_orders, 8)
-
-
 def agh(inst: Instance, R: int | None = None, L: int = 3, seed: int = 0,
         patience: int = 5, validate: bool = False,
         local_search: str = "batched",
-        workers: int | None = None,
         warm_start: Solution | None = None,
         priority_orders: list[np.ndarray] | None = None,
         stats: dict | None = None) -> Solution:
@@ -772,13 +685,11 @@ def agh(inst: Instance, R: int | None = None, L: int = 3, seed: int = 0,
     "batched-rescan" (the same engine with dirty-source tracking disabled:
     every sweep re-scores every source — the oracle the incremental mode
     is tested bit-equal against), or "reference" (the first-improvement
-    probe loop, bit-identical to the frozen scalar seed path).  `workers`
-    controls the multi-start driver: ``0`` forces
-    the sequential early-stop protocol, ``n >= 1`` evaluates every ordering
-    under the deterministic-reduction protocol (fanning out over ``n``
-    forked processes when ``n > 1``; results are independent of ``n``), and
-    ``None`` picks automatically — sequential below `_PARALLEL_MIN_N`,
-    fan-out above it.
+    probe loop, bit-identical to the frozen scalar seed path).  The two
+    non-default modes are test oracles; `validate` turns on per-move
+    consistency asserts.  Orderings run in this process under the
+    sequential early-stop rule: stop after `patience` consecutive
+    orderings that do not strictly improve the best objective.
 
     `warm_start` seeds the multi-start from an incumbent solution (the
     `PlanSession.replan` path): the incumbent's deployment is re-routed
@@ -797,11 +708,8 @@ def agh(inst: Instance, R: int | None = None, L: int = 3, seed: int = 0,
 
     `stats`, when given, is filled in place with solver diagnostics
     (orderings evaluated, local-search moves applied, drains, fallback
-    rescans, the winning ordering, warm-start provenance) — collected on
-    the sequential driver; the parallel fan-out reports ordering counts,
-    the winning ordering, and ``multi_start_inline``: True when a pool was
-    asked for but every ordering ran in this process (no fork once a JAX
-    backend is live, or no pool available).
+    rescans, whether the patience rule stopped the loop early, the winning
+    ordering, warm-start provenance).
     """
     t0 = time.perf_counter()
     rng = np.random.default_rng(seed)
@@ -813,13 +721,11 @@ def agh(inst: Instance, R: int | None = None, L: int = 3, seed: int = 0,
     if priority_orders:
         orders = [np.asarray(o) for o in priority_orders] + orders
     # Phase 1 is ordering-independent: run it once and share the snapshot
-    # with every start (and every forked worker).
+    # with every start.
     st0 = State.fresh(inst)
     _phase1(st0)
     p1_snap = state_snapshot(st0)
     ranked = None if batched else _rank_inactive_targets(inst)
-    if workers is None:
-        workers = _auto_workers(inst, len(orders)) if batched else 0
     if stats is not None:
         stats.update(restarts=R, warm_started=warm_start is not None,
                      local_search=local_search)
@@ -830,39 +736,24 @@ def agh(inst: Instance, R: int | None = None, L: int = 3, seed: int = 0,
         best, best_obj = solution_from_state(inst, st), state_objective(st)
         if stats is not None:
             stats["warm_objective"] = best_obj
-    if workers:
-        par, par_obj, par_idx, inline = _multi_start_parallel(
-            inst, orders, p1_snap, L, batched, ranked, validate, workers,
-            incremental=incremental)
-        # Same strict-improvement rule as the sequential reduction: the
-        # warm seed came first, so it wins ties.
-        if par_obj < best_obj - 1e-9:
-            best, best_obj = par, par_obj
-            best_order = orders[par_idx]
-        if stats is not None:
-            stats["orderings_evaluated"] = len(orders)
-            stats["multi_start_inline"] = inline
-    else:
-        stale = 0
-        evaluated = 0
-        for order in orders:
-            st = _run_ordering(inst, order, p1_snap, L, batched, ranked,
-                               validate, incremental=incremental,
-                               stats=stats)
-            evaluated += 1
-            obj = state_objective(st)
-            if obj < best_obj - 1e-9:
-                best, best_obj = solution_from_state(inst, st), obj
-                best_order = order
-                stale = 0
-            else:
-                stale += 1
-                if stale >= patience:
-                    break
-        if stats is not None:
-            stats["orderings_evaluated"] = evaluated
-            stats["early_stopped"] = evaluated < len(orders)
+    stale = 0
+    evaluated = 0
+    for order in orders:
+        st = _run_ordering(inst, order, p1_snap, L, batched, ranked,
+                           validate, incremental=incremental, stats=stats)
+        evaluated += 1
+        obj = state_objective(st)
+        if obj < best_obj - 1e-9:
+            best, best_obj = solution_from_state(inst, st), obj
+            best_order = order
+            stale = 0
+        else:
+            stale += 1
+            if stale >= patience:
+                break
     if stats is not None:
+        stats["orderings_evaluated"] = evaluated
+        stats["early_stopped"] = evaluated < len(orders)
         # The ordering whose basin won (None when the warm seed held) —
         # `PlanSession` replays it on the next replan.
         stats["winning_order"] = (None if best_order is None

@@ -41,16 +41,15 @@ class EvalResult:
 
 def evaluate(inst: Instance, deploy: Solution, S: int = 500, seed: int = 1234,
              d_infl: float = 0.15, e_infl: float = 0.10, lam_pm: float = 0.20,
-             u_cap: np.ndarray | None = None, batched: bool = True,
-             workers: int | None = None) -> EvalResult:
+             u_cap: np.ndarray | None = None,
+             batched: bool = True) -> EvalResult:
     rng = np.random.default_rng(seed)
     s1 = provisioning_cost(inst, deploy)
     if batched:
         batch = inst.perturbed_batch(rng, S, d_infl=d_infl, e_infl=e_infl,
                                      lam_pm=lam_pm)
         system = Stage2System(inst, deploy)
-        costs, viols, _ = system.solve_batch(batch, u_cap=u_cap,
-                                             workers=workers)
+        costs, viols, _ = system.solve_batch(batch, u_cap=u_cap)
         viol = int(viols.sum())
     else:
         costs = np.zeros(S)

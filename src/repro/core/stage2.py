@@ -23,8 +23,8 @@ one-factor rescale of a per-triple base array:
 in place per scenario, and solves scenarios back-to-back through HiGHS via
 `scipy.optimize.milp` — the thin wrapper; scipy exposes no basis warm-start
 API, so structure reuse is the part of the warm start we can keep.
-`solve_batch` runs a whole `ScenarioBatch` this way, optionally fanned out
-over a process pool.  No per-scenario `Instance` (nor its [I,J,K,C] tensor
+`solve_batch` runs a whole `ScenarioBatch` this way, in this process.
+No per-scenario `Instance` (nor its [I,J,K,C] tensor
 rebuild) is materialized anywhere on this path.
 
 Equivalence with the frozen per-call assembly (`_scalar_ref.stage2_lp_ref`)
@@ -40,21 +40,6 @@ from scipy.optimize import Bounds, LinearConstraint, milp
 
 from .instance import KB_PER_GB, T_CONV, Instance, ScenarioBatch
 from .solution import Solution, cost_terms
-
-# Optional true basis warm-start across scenarios (ROADMAP risk item):
-# scipy's HiGHS wrappers rebuild the solver per call, discarding the
-# optimal basis between scenarios.  When the `highspy` bindings are
-# installed, `solve_batch(warm_start=...)` can instead drive one
-# persistent Highs model whose basis carries over from scenario to
-# scenario.  The import is gated — this container (and CI) ships without
-# highspy, and the scipy path stays the byte-identical default.
-try:
-    import highspy
-except ImportError:            # pragma: no cover - exercised via the flag
-    highspy = None
-
-HAVE_HIGHSPY = highspy is not None
-
 
 @dataclasses.dataclass
 class _LPResult:
@@ -312,50 +297,22 @@ class Stage2System:
                          viol=int(np.sum(u > 0.01)))
 
     def solve_batch(self, batch: ScenarioBatch,
-                    u_cap: np.ndarray | None = None,
-                    workers: int | None = None,
-                    warm_start: bool | None = None
+                    u_cap: np.ndarray | None = None
                     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Solve every scenario of `batch` against the fixed deployment.
-
-        Returns (costs[S], viols[S], capped_ok[S]).  With `workers`, the
-        scenario list is fanned out over a process pool (each worker reuses
-        this system's pattern; chunked to amortize pickling).
-
-        `warm_start` requests the persistent-Highs basis warm start across
-        scenarios (sequential only; requires the optional `highspy`
-        bindings).  `None` means "use it when available and sequential";
-        `True` raises if highspy is absent — the scipy path is never
-        silently swapped out.
-        """
+        """Solve every scenario of `batch` against the fixed deployment,
+        one after another.  Returns (costs[S], viols[S], capped_ok[S])."""
         S = batch.S
-        if warm_start and not HAVE_HIGHSPY:
-            raise RuntimeError(
-                "warm_start=True requires the optional highspy bindings; "
-                "install highspy or pass warm_start=False/None")
-        use_pool = workers and workers > 1 and S >= 2 * workers
-        if warm_start is None:
-            warm_start = HAVE_HIGHSPY and not use_pool
-        if warm_start and not use_pool:
-            return _solve_chunk_highspy(self, batch, u_cap)
-        if use_pool:
-            import concurrent.futures as cf
-            import multiprocessing as mp
-            chunks = np.array_split(np.arange(S), workers)
-            parts = []
-            # spawn, not fork: the parent is typically multithreaded (jax,
-            # BLAS) and forking such a process can deadlock the children.
-            with cf.ProcessPoolExecutor(
-                    max_workers=workers,
-                    mp_context=mp.get_context("spawn")) as ex:
-                futs = [ex.submit(_solve_chunk, self, _batch_slice(batch, c),
-                                  u_cap) for c in chunks if c.size]
-                parts = [f.result() for f in futs]
-            costs = np.concatenate([p[0] for p in parts])
-            viols = np.concatenate([p[1] for p in parts])
-            capped = np.concatenate([p[2] for p in parts])
-            return costs, viols, capped
-        return _solve_chunk(self, batch, u_cap)
+        costs = np.zeros(S)
+        viols = np.zeros(S, dtype=np.int64)
+        capped = np.zeros(S, dtype=bool)
+        for s in range(S):
+            r = self.solve(
+                tau=None if batch.tau is None else batch.tau[s],
+                e_base=None if batch.e_base is None else batch.e_base[s],
+                lam=None if batch.lam is None else batch.lam[s],
+                u_cap=u_cap)
+            costs[s], viols[s], capped[s] = r.cost, r.viol, r.capped_ok
+        return costs, viols, capped
 
     def materialize(self, r: _LPResult) -> Solution:
         """Legacy `stage2_lp` output: deployment copy + scenario routing."""
@@ -364,106 +321,6 @@ class Stage2System:
             sol.x[self.ti, self.tj, self.tk] = r.x
         sol.u = r.u.copy()
         return sol
-
-
-def _batch_slice(batch: ScenarioBatch, idx: np.ndarray) -> ScenarioBatch:
-    pick = lambda a: None if a is None else a[idx]
-    return ScenarioBatch(S=idx.size, tau=pick(batch.tau),
-                         e_base=pick(batch.e_base), lam=pick(batch.lam))
-
-
-def _solve_chunk(system: Stage2System, batch: ScenarioBatch,
-                 u_cap: np.ndarray | None
-                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Sequential scenario loop over one chunk (process-pool task body)."""
-    S = batch.S
-    costs = np.zeros(S)
-    viols = np.zeros(S, dtype=np.int64)
-    capped = np.zeros(S, dtype=bool)
-    for s in range(S):
-        r = system.solve(
-            tau=None if batch.tau is None else batch.tau[s],
-            e_base=None if batch.e_base is None else batch.e_base[s],
-            lam=None if batch.lam is None else batch.lam[s],
-            u_cap=u_cap)
-        costs[s], viols[s], capped[s] = r.cost, r.viol, r.capped_ok
-    return costs, viols, capped
-
-
-def _solve_chunk_highspy(system: Stage2System, batch: ScenarioBatch,
-                         u_cap: np.ndarray | None
-                         ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Sequential chunk via one persistent Highs model (basis warm start).
-
-    Mirrors `_solve_chunk`'s strict-cap-then-relax protocol; only the LP
-    backend differs.  HiGHS keeps the previous optimal basis between
-    `run()` calls on the same model, so consecutive scenarios — one-factor
-    rescales of each other — typically re-optimize in a handful of dual
-    simplex iterations instead of solving from scratch.
-    """
-    if highspy is None:          # pragma: no cover - guarded by callers
-        raise RuntimeError("highspy is not installed")
-    inst = system.inst
-    cap = inst.zeta if u_cap is None else u_cap
-    S = batch.S
-    costs = np.zeros(S)
-    viols = np.zeros(S, dtype=np.int64)
-    capped = np.zeros(S, dtype=bool)
-
-    h = highspy.Highs()
-    h.setOptionValue("output_flag", False)
-    lp = highspy.HighsLp()
-    lp.num_col_ = system.n
-    lp.num_row_ = system.m
-    lp.col_cost_ = np.zeros(system.n)
-    lp.col_lower_ = system._lb.copy()
-    ub0 = np.ones(system.n)
-    ub0[system.nx:] = cap
-    lp.col_upper_ = ub0
-    lp.row_lower_ = system.row_lb.copy()
-    lp.row_upper_ = system.row_ub.copy()
-    lp.a_matrix_.format_ = highspy.MatrixFormat.kColwise
-    lp.a_matrix_.start_ = system.A.indptr.astype(np.int32)
-    lp.a_matrix_.index_ = system.A.indices.astype(np.int32)
-    lp.a_matrix_.value_ = system._vals[system._perm].copy()
-    h.passModel(lp)
-
-    col_idx = np.arange(system.n, dtype=np.int32)
-    u_idx = col_idx[system.nx:]
-    u_lb = np.zeros(system.I)
-    rows_ineq = system.rows_all[:system.nnz]
-    cols_ineq = system.cols_all[:system.nnz]
-    kOptimal = highspy.HighsModelStatus.kOptimal
-
-    def _run(c: np.ndarray, u_ub: np.ndarray) -> tuple[bool, np.ndarray]:
-        h.changeColsCost(system.n, col_idx, c)
-        h.changeColsBounds(system.I, u_idx, u_lb, u_ub)
-        h.run()
-        if h.getModelStatus() != kOptimal:
-            return False, np.zeros(system.n)
-        return True, np.array(h.getSolution().col_value)
-
-    for s in range(S):
-        vals, c = system._coefficients(
-            inst.tau if batch.tau is None else batch.tau[s],
-            inst.e_base if batch.e_base is None else batch.e_base[s],
-            inst.lam if batch.lam is None else batch.lam[s])
-        for e in range(system.nnz):
-            h.changeCoeff(int(rows_ineq[e]), int(cols_ineq[e]),
-                          float(vals[e]))
-        ok, xfull = _run(c, cap)
-        capped[s] = ok
-        if not ok:
-            ok, xfull = _run(c, np.ones(system.I))
-        if ok:
-            u = np.clip(xfull[system.nx:], 0.0, 1.0)
-            costs[s] = float(c[:system.nx] @ xfull[:system.nx]
-                             + system.c_u @ u)
-        else:
-            u = np.ones(system.I)
-            costs[s] = float(system.c_u @ u)
-        viols[s] = int(np.sum(u > 0.01))
-    return costs, viols, capped
 
 
 def stage2_lp(inst: Instance, deploy: Solution, u_cap: np.ndarray | None = None,

@@ -85,14 +85,6 @@ class _Lane:
         self.kk: np.ndarray | None = None
 
 
-def _chunked(seq, width):
-    if not width or width >= len(seq):
-        yield seq
-        return
-    for i in range(0, len(seq), width):
-        yield seq[i:i + width]
-
-
 # ---------------------------------------------------------------------------
 # Phase 2 in lockstep: device keys, exact host walk
 # ---------------------------------------------------------------------------
@@ -116,30 +108,30 @@ def _phase2_item(ln: _Lane, i: int, c_arr: np.ndarray,
             float(st.D_used[i]), jj * inst.K + kk, cost_a, d_a, cc >= 0)
 
 
-def _phase2_lockstep(lanes: list[_Lane], tx, batch_width: int | None,
-                     counters: dict) -> None:
+def _phase2_lockstep(lanes: list[_Lane], tx, counters: dict) -> None:
+    """GH Phase 2 on every lane: one device call per type step packs all
+    lanes' keys."""
     inst = lanes[0].st.inst
     for ln in lanes:
         ln.active = ln.st.q > 0.5
         ln.jj, ln.kk = np.nonzero(ln.active)
     for t in range(inst.I):
-        for chunk in _chunked(lanes, batch_width):
-            with span("xla.phase2.pack"):
-                preps = []
-                for ln in chunk:
-                    i = int(ln.order[t])
-                    c_arr, d_sel = _phase2_prep(ln.st, i, ln.active, ln.jj,
-                                                ln.kk)
-                    preps.append((i, c_arr, d_sel))
-                items = [_phase2_item(ln, i, c_arr, d_sel) for ln, (i, c_arr, d_sel)
-                         in zip(chunk, preps, strict=True)]
-            kap0, kap1 = kernels.phase2_keys(tx, items, counters)
-            with span("agh.phase2.walk"):
-                for r, ln in enumerate(chunk):
-                    i, c_arr, _ = preps[r]
-                    ln.jj, ln.kk = _phase2_walk(ln.st, i, c_arr, kap0[r],
-                                                kap1[r], ln.active, ln.jj,
-                                                ln.kk)
+        with span("xla.phase2.pack"):
+            preps = []
+            for ln in lanes:
+                i = int(ln.order[t])
+                c_arr, d_sel = _phase2_prep(ln.st, i, ln.active, ln.jj,
+                                            ln.kk)
+                preps.append((i, c_arr, d_sel))
+            items = [_phase2_item(ln, i, c_arr, d_sel) for ln, (i, c_arr, d_sel)
+                     in zip(lanes, preps, strict=True)]
+        kap0, kap1 = kernels.phase2_keys(tx, items, counters)
+        with span("agh.phase2.walk"):
+            for r, ln in enumerate(lanes):
+                i, c_arr, _ = preps[r]
+                ln.jj, ln.kk = _phase2_walk(ln.st, i, c_arr, kap0[r],
+                                            kap1[r], ln.active, ln.jj,
+                                            ln.kk)
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +139,7 @@ def _phase2_lockstep(lanes: list[_Lane], tx, batch_width: int | None,
 # ---------------------------------------------------------------------------
 
 def _relocate_screened(st: State, L: int, validate: bool,
-                       cache: DestCache, clean: set | None,
+                       cache: DestCache, clean: set,
                        counters: dict, rescan: bool = False):
     """`_relocate_batched(fallback=False)` with the dirty-source scans
     gated by a device screen: a generator that yields
@@ -168,12 +160,10 @@ def _relocate_screened(st: State, L: int, validate: bool,
     StopIteration) whether any move was applied."""
     inst = st.inst
     K = inst.K
-    track = clean is not None
     improving = 0
     any_improved = False
     while True:
         improved = False
-        skipped = False
         obj = state_objective(st)
         screen_fail: set = set()
         # One sweep-start enumeration in (type, cell) row-major order —
@@ -181,12 +171,11 @@ def _relocate_screened(st: State, L: int, validate: bool,
         ii, ff = np.nonzero(st.x.reshape(inst.I, -1) > 1e-9)
         all_sources = [(int(i), int(f) // K, int(f) % K)
                        for i, f in zip(ii, ff, strict=True)]
-        if track:
-            sources = [s for s in all_sources if s not in clean]
-            if sources:
-                verdicts = yield ("screen", obj, sources, rescan)
-                screen_fail = {s for s, ok in zip(sources, verdicts, strict=True)
-                               if not ok}
+        sources = [s for s in all_sources if s not in clean]
+        if sources:
+            verdicts = yield ("screen", obj, sources, rescan)
+            screen_fail = {s for s, ok in zip(sources, verdicts, strict=True)
+                           if not ok}
         stats_bucket = None
         if "_screen_stats" in counters:
             stats_bucket = counters["_screen_stats"][
@@ -194,8 +183,7 @@ def _relocate_screened(st: State, L: int, validate: bool,
         for (i, j, k) in all_sources:
             if st.x[i, j, k] <= 1e-9:   # merged away earlier this sweep
                 continue
-            if track and (i, j, k) in clean:
-                skipped = True
+            if (i, j, k) in clean:
                 continue
             if (i, j, k) in screen_fail and not improved:
                 clean.add((i, j, k))
@@ -212,8 +200,7 @@ def _relocate_screened(st: State, L: int, validate: bool,
             counters["scan_s"] = (counters.get("scan_s", 0.0)
                                   + time.perf_counter() - t0)
             if not ms.admissible.any():
-                if track:
-                    clean.add((i, j, k))
+                clean.add((i, j, k))
                 continue
             flat = int(np.argmin(ms.obj_after))
             j2, k2 = flat // K, flat % K
@@ -224,7 +211,7 @@ def _relocate_screened(st: State, L: int, validate: bool,
             counters["moves_applied"] = \
                 counters.get("moves_applied", 0) + 1
             cache.invalidate_type(i)
-            if track and clean:
+            if clean:
                 cells = set()
                 if np.count_nonzero(st.x[:, j, k] > 1e-9) == 1:
                     cells.add((j, k))
@@ -237,9 +224,8 @@ def _relocate_screened(st: State, L: int, validate: bool,
             if improving >= L:
                 break
         else:
-            # No fallback rescan here (the caller's verification rescan
-            # covers it); `skipped` sweeps end like non-tracking ones.
-            del skipped
+            # No fallback rescan here: the caller's verification rescan
+            # covers it.
             break
     return any_improved
 
@@ -256,7 +242,7 @@ def _improve_lane(ln: _Lane, L: int, validate: bool, counters: dict):
         if _consolidate_batched(st, validate, cache, clean,
                                 stats=counters):
             continue
-        if not (clean is not None and clean):
+        if not clean:
             return
         clean.clear()
         counters["rescans"] = counters.get("rescans", 0) + 1
@@ -392,8 +378,7 @@ def _screen_batch(tx, requests: list[tuple], load: np.ndarray,
 
 
 def _improve_wave(wave: list[_Lane], offset: int, tx, L: int,
-                  validate: bool, incremental: bool, counters: dict,
-                  load: np.ndarray) -> None:
+                  validate: bool, counters: dict, load: np.ndarray) -> None:
     """Run the improvement loop of one wave of lanes in lockstep.
 
     ``offset`` is the wave's position in the full lane list — lane
@@ -402,7 +387,7 @@ def _improve_wave(wave: list[_Lane], offset: int, tx, L: int,
     pending: list[tuple] = []
     for idx, ln in enumerate(wave):
         ln.cache = DestCache(ln.st)
-        ln.clean = set() if incremental else None
+        ln.clean = set()
         gen = _improve_lane(ln, L, validate, counters)
         try:
             with span("agh.relocate"):
@@ -426,8 +411,7 @@ def _improve_wave(wave: list[_Lane], offset: int, tx, L: int,
 
 
 def _improve_lockstep(lanes: list[_Lane], tx, L: int, validate: bool,
-                      incremental: bool, patience: int, counters: dict,
-                      batch_width: int | None = None) -> int:
+                      patience: int, counters: dict) -> int:
     """Improve lanes in lane-order waves with the sequential early-stop
     rule replayed between waves.
 
@@ -452,17 +436,11 @@ def _improve_lockstep(lanes: list[_Lane], tx, L: int, validate: bool,
         # rule could still evaluate before stopping (`patience` minus
         # the prefix's trailing stale streak), so the improved prefix
         # never overshoots the sequential stop point by more than the
-        # wave that contains it.  A `batch_width` cap shrinks the waves,
-        # which replays the stop rule more often — the evaluated prefix
-        # stays a superset of the sequential driver's for any wave
-        # partition.
+        # wave that contains it.
         take = (max(patience + 1, 8) + sum(ln.is_warm for ln in lanes)
                 if done == 0 else max(patience - stale, 1))
-        if batch_width:
-            take = min(take, batch_width)
         wave = lanes[done:done + take]
-        _improve_wave(wave, done, tx, L, validate, incremental, counters,
-                      load)
+        _improve_wave(wave, done, tx, L, validate, counters, load)
         done += len(wave)
         best_obj, stale = np.inf, 0
         for ln in lanes[:done]:
@@ -484,11 +462,9 @@ def _improve_lockstep(lanes: list[_Lane], tx, L: int, validate: bool,
 
 def agh_xla(inst: Instance, R: int | None = None, L: int = 3,
             seed: int = 0, patience: int = 5, validate: bool = False,
-            local_search: str = "batched", workers: int | None = None,
             warm_start: Solution | None = None,
             priority_orders: list[np.ndarray] | None = None,
-            stats: dict | None = None,
-            batch_width: int | None = None) -> Solution:
+            stats: dict | None = None) -> Solution:
     """AGH on the XLA engine — drop-in for `core.agh.agh`.
 
     Construction (Phase 2) runs on every lane; the improvement loop
@@ -496,15 +472,8 @@ def agh_xla(inst: Instance, R: int | None = None, L: int = 3,
     device-batched waves and the sequential early-stop rule is replayed
     between waves, so the evaluated set is always a superset of the
     sequential numpy driver's and the returned objective can only match
-    or beat it.  `workers` is accepted for signature compatibility and
-    ignored (the lane batch replaces the process pool).  `batch_width`
-    caps how many lanes advance together — per device call in the
-    Phase-2 lockstep and per improvement wave — the knob behind the
-    benchmark's batch-width scaling curve; ``None`` batches all lanes at
-    once.  Narrower waves replay the early-stop rule more often (width 1
-    = the exact sequential protocol), so results across widths are
-    dominance-ordered, not identical, unless patience is effectively
-    infinite.
+    or beat it.  Local search is always the incremental scored-matrix
+    engine (`agh`'s default ``local_search="batched"``).
     """
     t0 = time.perf_counter()
     if inst.avail_gpus is not None:
@@ -515,15 +484,8 @@ def agh_xla(inst: Instance, R: int | None = None, L: int = 3,
         if stats is not None:
             stats["xla_avail_fallback"] = True
         return _agh_numpy(inst, R=R, L=L, seed=seed, patience=patience,
-                          validate=validate, local_search=local_search,
-                          workers=workers, warm_start=warm_start,
+                          validate=validate, warm_start=warm_start,
                           priority_orders=priority_orders, stats=stats)
-    if local_search == "reference":
-        raise ValueError("engine='xla' does not implement "
-                         "local_search='reference'; use 'batched' or "
-                         "'batched-rescan'")
-    del workers   # the lane batch replaces the process pool
-    incremental = local_search != "batched-rescan"
     with span("agh.setup"):
         rng = np.random.default_rng(seed)
         if R is None:
@@ -545,9 +507,8 @@ def agh_xla(inst: Instance, R: int | None = None, L: int = 3,
             st = State.fresh(inst)
             state_restore(st, p1)
             lanes.append(_Lane(st, np.asarray(order)))
-    _phase2_lockstep(lanes, tx, batch_width, counters)
-    done = _improve_lockstep(lanes, tx, L, validate, incremental,
-                             patience, counters, batch_width)
+    _phase2_lockstep(lanes, tx, counters)
+    done = _improve_lockstep(lanes, tx, L, validate, patience, counters)
     with span("agh.reduce"):
         # Deterministic reduction over the improved prefix, in lane order;
         # the warm lane comes first and therefore wins ties, matching the
@@ -567,7 +528,6 @@ def agh_xla(inst: Instance, R: int | None = None, L: int = 3,
         if stats is not None:
             stats.update(engine="xla", restarts=R,
                          warm_started=warm_start is not None,
-                         local_search=local_search,
                          orderings_evaluated=done - n_warm,
                          early_stopped=done < len(lanes),
                          winning_order=(None if best_order is None

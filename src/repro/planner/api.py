@@ -4,7 +4,7 @@ One entry point replaces the five loose solver functions (`gh`, `agh`,
 `solve_milp`, `dvr`/`hf`/`lpr`) and their divergent kwargs:
 
 * `PlanOptions` — the typed option set every solver draws from (restarts,
-  local-search mode, workers, time limit, ...).  Irrelevant options are
+  engine, patience, time limit, ...).  Irrelevant options are
   ignored by construction (each adapter picks the fields it understands),
   but the *names* are checked: `PlanOptions` is a frozen dataclass, so a
   typo'd option fails at the call site instead of vanishing into `**kw`.
@@ -44,15 +44,9 @@ class PlanOptions:
     |                |                    | (None = Remark-2 adaptive)     |
     | ``passes``     | agh                | local-search pass cap L        |
     | ``patience``   | agh                | early-stop patience            |
-    | ``local_search``| agh               | "batched" / "batched-rescan" / |
-    |                |                    | "reference"                    |
     | ``engine``     | agh                | "numpy" (default, the oracle)  |
     |                |                    | / "xla" (jitted batched tier;  |
     |                |                    | needs jax, loaded lazily)      |
-    | ``batch_width``| agh (engine=xla)   | lanes per device call in the   |
-    |                |                    | lockstep batch (None = all)    |
-    | ``workers``    | agh                | multi-start fan-out width      |
-    | ``validate``   | agh                | per-move debug consistency     |
     | ``order``      | gh                 | Phase-2 type ordering override |
     | ``run_phase1`` | gh                 | coverage pre-allocation on/off |
     | ``ablation``   | gh                 | M1/M2/M3 ablation switches     |
@@ -75,11 +69,7 @@ class PlanOptions:
     restarts: int | None = None
     passes: int = 3
     patience: int = 5
-    local_search: str = "batched"
     engine: str = "numpy"
-    batch_width: int | None = None
-    workers: int | None = None
-    validate: bool = False
     order: tuple[int, ...] | None = None
     run_phase1: bool = True
     ablation: frozenset = frozenset()

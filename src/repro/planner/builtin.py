@@ -34,7 +34,6 @@ def _solve_agh(inst, options, warm_start):
     priority = ([np.asarray(options.order)]
                 if options.order is not None else None)
     engine = getattr(options, "engine", "numpy") or "numpy"
-    extra = {}
     if engine == "xla":
         # Lazy tier load: jax is only imported when the xla engine is
         # actually requested (`from repro import plan` stays jax-free;
@@ -42,18 +41,15 @@ def _solve_agh(inst, options, warm_start):
         # ModuleNotFoundError).
         from repro.core.xla import load_engine
         solver = load_engine().agh_xla
-        extra["batch_width"] = options.batch_width
     elif engine == "numpy":
         solver = agh
     else:
         raise ValueError(f"unknown engine {engine!r}: "
                          "expected 'numpy' or 'xla'")
-    sol = solver(inst, R=options.restarts, L=options.passes, **extra,
+    sol = solver(inst, R=options.restarts, L=options.passes,
                  seed=options.seed, patience=options.patience,
-                 validate=options.validate,
-                 local_search=options.local_search,
-                 workers=options.workers, warm_start=warm_start,
-                 priority_orders=priority, stats=stats)
+                 warm_start=warm_start, priority_orders=priority,
+                 stats=stats)
     stats["active_pairs"] = int(np.sum(sol.q > 0.5))
     return sol, stats
 

@@ -144,15 +144,10 @@ class PlanSession:
                 # Fast-replan protocol: tighter patience, no random restarts,
                 # and the incumbent's winning ordering replayed first (the
                 # multi-start winner is empirically stable under drift — see
-                # core/agh.py `priority_orders`).  The sequential driver is
-                # pinned unless the caller set workers explicitly: AGH's
-                # auto fan-out evaluates EVERY ordering with no early stop,
-                # which would silently discard the patience the warm seed
-                # buys — exactly at the fleet scales where auto engages.
+                # core/agh.py `priority_orders`).
                 opts = dataclasses.replace(
                     opts, patience=self.replan_patience,
-                    restarts=self.replan_restarts, order=self.winning_order,
-                    workers=0 if opts.workers is None else opts.workers)
+                    restarts=self.replan_restarts, order=self.winning_order)
             res = plan(PlanRequest(solver=self.solver, instance=inst,
                                    options=opts, warm_start=self.incumbent))
             self._install(inst, res, warm=warm, kind="replan", cause=cause)
@@ -269,8 +264,7 @@ class PlanSession:
                 and self.incumbent.x.shape == (inst.I, inst.J, inst.K)):
             stats: dict = {}
             sol = agh_repair(inst, self.incumbent, L=max(1, passes),
-                             local_search=self.options.local_search,
-                             validate=self.options.validate, stats=stats)
+                             stats=stats)
             return sol, stats, True
         sol, diag = spec.solve(inst, self.options, None)
         return sol, dict(diag), False

@@ -1,33 +1,18 @@
 """Process-wide JAX state that belongs to entry points, not to imports.
 
-Library modules neither configure JAX's persistent compilation cache nor
-fork once a JAX backend is live.  Entry points (`chip_smoke.py`,
-`benchmarks/run.py`) call `use_compile_cache()` once, before their first
-compile; the multi-start pool asks `backend_initialized()` before it
-forks.
+Library modules never configure JAX's persistent compilation cache.
+Entry points (`chip_smoke.py`, `benchmarks/run.py`, the benchmark
+harness) call `use_compile_cache()` once, before their first compile.
 """
 from __future__ import annotations
 
 import os
-import sys
 from pathlib import Path
 
 #: The checkout's own cache directory (``src/repro/`` -> checkout root).
 #: A fixed path: the directory is part of the cache key, so one that
 #: moves between runs never hits.
 CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
-
-
-def backend_initialized() -> bool:
-    """True once this process has initialised a JAX backend.
-
-    Such a process may hold the accelerator, and a forked child would
-    inherit the device client's threads and handles without owning the
-    chip, so callers run their work inline instead of forking."""
-    if "jax" not in sys.modules:
-        return False
-    from jax._src import xla_bridge
-    return xla_bridge.backends_are_initialized()
 
 
 def use_compile_cache() -> str:

@@ -20,7 +20,7 @@ from repro.core import (CapacityShock, FaultSchedule, PriceSpike, Recovery,
                         evict_unavailable, is_feasible, lost_pairs,
                         poisson_revocations, random_instance, rolling,
                         with_spot_tiers)
-from repro.planner import PlanOptions, PlanSession
+from repro.planner import PlanSession
 
 
 def _binding(inst, zeta: float = 0.1):
@@ -290,15 +290,13 @@ def test_faulted_replay_repair_dominates_static_and_matches_cold():
     lam_path = np.clip(
         inst.lam[None, :] * (1.0 + 0.1 * rng.standard_normal((T, inst.I))),
         0.0, None)
-    opts = PlanOptions(workers=0)
-
     def bare(inst):
         from repro.planner import plan
-        return plan("agh", instance=inst, options=opts).solution
+        return plan("agh", instance=inst).solution
 
     results = {}
     for mode in ("repair", "cold", "static"):
-        planner = PlanSession(options=opts) if mode == "repair" else bare
+        planner = PlanSession() if mode == "repair" else bare
         results[mode] = rolling(
             spot, lam_path, planner,
             replan_every=(None if mode == "static" else 4),
@@ -311,9 +309,9 @@ def test_faulted_replay_repair_dominates_static_and_matches_cold():
     assert (results["repair"].violation_rate
             <= results["cold"].violation_rate + 1e-9)
     # fault-free replay is untouched by the new kwargs (identity default)
-    r_empty = rolling(spot, lam_path, PlanSession(options=opts),
+    r_empty = rolling(spot, lam_path, PlanSession(),
                       replan_every=4, faults=FaultSchedule(T, ()))
-    r_none = rolling(spot, lam_path, PlanSession(options=opts),
+    r_none = rolling(spot, lam_path, PlanSession(),
                      replan_every=4)
     assert np.allclose(r_empty.per_window_cost, r_none.per_window_cost)
 

@@ -12,8 +12,7 @@ Three contracts pin the PR-3 engine:
   the full batched AGH never returns a worse objective than
   `local_search="reference"`;
 * every solution the batched engine returns passes the full constraint
-  system (`is_feasible`), and the parallel multi-start driver returns the
-  identical solution for any worker count.
+  system (`is_feasible`).
 """
 import numpy as np
 import pytest
@@ -161,41 +160,3 @@ def test_batched_agh_never_worse_and_feasible(name, inst):
     assert ob <= orf + 1e-9, (name, ob, orf)
     # and never worse than plain GH
     assert ob <= objective(inst, gh(inst)) + 1e-9, name
-
-
-def _allow_fork(monkeypatch):
-    """Keep the pool leg under test even in a test process that has
-    already started a JAX backend (the pool then runs inline)."""
-    import repro.runtime
-    monkeypatch.setattr(repro.runtime, "backend_initialized", lambda: False)
-
-
-def _pooled(inst, workers):
-    stats = {}
-    sol = agh(inst, workers=workers, stats=stats)
-    assert stats["multi_start_inline"] is False
-    return sol
-
-
-def test_parallel_multi_start_worker_count_invariant(monkeypatch):
-    """The deterministic-reduction protocol returns the identical solution
-    for any worker count (inline, 2 procs, 3 procs) given the same seed."""
-    _allow_fork(monkeypatch)
-    inst = random_instance(15, 15, 10, seed=9)
-    sols = [_pooled(inst, w) for w in (1, 2, 3)]
-    for s in sols[1:]:
-        for field in ("x", "y", "q", "z", "w", "u"):
-            assert np.array_equal(getattr(s, field), getattr(sols[0], field))
-    # and it is never worse than the sequential early-stop protocol, which
-    # evaluates a prefix of the same orderings
-    seq = agh(inst, workers=0)
-    assert objective(inst, sols[0]) <= objective(inst, seq) + 1e-9
-
-
-def test_parallel_multi_start_matches_inline_on_default(monkeypatch):
-    _allow_fork(monkeypatch)
-    inst = default_instance()
-    par = _pooled(inst, 2)
-    inline = agh(inst, workers=1)
-    for field in ("x", "y", "q", "z", "w", "u"):
-        assert np.array_equal(getattr(par, field), getattr(inline, field))

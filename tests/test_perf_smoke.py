@@ -87,7 +87,7 @@ def test_agh_paper_scale_100_80_40_wall():
     incremental local search, not on CI machine noise."""
     inst = random_instance(100, 80, 40, seed=42)
     t0 = time.perf_counter()
-    sol = agh(inst, workers=0)
+    sol = agh(inst)
     wall = time.perf_counter() - t0
     assert wall < 6.0, f"AGH took {wall:.2f}s on (100,80,40)"
     assert sol.u.max() <= 1.0 + 1e-9
@@ -102,17 +102,17 @@ def test_warm_replan_matches_cold_quality_at_lower_wall():
     winning ordering lands in the same basin).  The bars below only fire
     on a real regression: quality must never be worse, and the warm path
     must keep a >= 1.3x advantage."""
-    from repro.planner import PlanOptions, PlanSession, plan
+    from repro.planner import PlanSession, plan
 
     inst = random_instance(100, 80, 40, seed=42)
     drift = np.random.default_rng(7).uniform(0.85, 1.15, inst.I)
     drifted = inst.with_lam(inst.lam * drift)
 
     t0 = time.perf_counter()
-    cold = plan("agh", instance=drifted, options=PlanOptions(workers=0))
+    cold = plan("agh", instance=drifted)
     t_cold = time.perf_counter() - t0
 
-    ses = PlanSession(options=PlanOptions(workers=0))
+    ses = PlanSession()
     ses.plan(instance=inst)
     t0 = time.perf_counter()
     warm = ses.replan(instance=drifted)
@@ -134,10 +134,10 @@ def test_repair_subsecond_at_fleet_scale():
     cheaper than replanning from scratch."""
     import dataclasses
 
-    from repro.planner import PlanOptions, PlanSession
+    from repro.planner import PlanSession
 
     inst = random_instance(100, 80, 40, seed=42)
-    sess = PlanSession(options=PlanOptions(workers=0))
+    sess = PlanSession()
     res0 = sess.plan(instance=inst)
     y_tier = res0.solution.y.sum(axis=0)
     busiest = int(np.argmax(y_tier))

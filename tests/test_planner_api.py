@@ -56,11 +56,9 @@ def test_facade_agh_bitwise_equals_direct(name, inst):
 
 def test_facade_agh_options_map_through():
     inst = random_instance(6, 6, 10, seed=1)
-    opts = PlanOptions(restarts=2, patience=3, seed=5,
-                       local_search="batched-rescan", workers=0)
+    opts = PlanOptions(restarts=2, passes=2, patience=3, seed=5)
     res = plan("agh", instance=inst, options=opts)
-    direct = agh(inst, R=2, patience=3, seed=5,
-                 local_search="batched-rescan", workers=0)
+    direct = agh(inst, R=2, L=2, patience=3, seed=5)
     _assert_bitwise_equal(res.solution, direct, "agh/options")
 
 
@@ -207,6 +205,15 @@ def test_plan_request_validation():
         plan(PlanRequest(solver="gh", instance=inst), instance=inst)
 
 
+@pytest.mark.parametrize("name", ["workers", "batch_width", "local_search",
+                                  "validate"])
+def test_plan_options_has_no_sweep_or_test_option(name):
+    """Options that only sweeps and tests set are keywords of
+    `core.agh.agh`, not fields of the facade."""
+    with pytest.raises(TypeError):
+        PlanOptions(**{name: 0})
+
+
 def test_plan_options_round_trip():
     opts = PlanOptions(restarts=4, ablation=frozenset({"no_m1"}),
                        order=(2, 0, 1))
@@ -279,7 +286,7 @@ def test_plan_accepts_scenario_names():
 
 def test_session_cold_then_warm_replan():
     inst = random_instance(6, 6, 10, seed=1)
-    ses = PlanSession(options=PlanOptions(workers=0))
+    ses = PlanSession()
     r0 = ses.plan(instance=inst)
     assert ses.plans == 1 and ses.warm_replans == 0
     assert not r0.diagnostics["warm_started"]
@@ -304,7 +311,7 @@ def test_session_without_incumbent_degrades_to_cold():
 
 def test_session_remembers_winning_order():
     inst = random_instance(8, 5, 6, seed=2)
-    ses = PlanSession(options=PlanOptions(workers=0))
+    ses = PlanSession()
     ses.plan(instance=inst)
     if ses.winning_order is not None:
         assert sorted(ses.winning_order) == list(range(inst.I))
@@ -319,8 +326,7 @@ def test_session_drives_rolling_replay():
     from repro.core import rolling
     inst = default_instance()
     path = np.outer(np.linspace(0.9, 1.1, 12), inst.lam)
-    ses = PlanSession(options=PlanOptions(restarts=1, patience=2,
-                                          workers=0))
+    ses = PlanSession(options=PlanOptions(restarts=1, patience=2))
     res = rolling(inst, path, ses, replan_every=4)
     assert res.per_window_cost.shape == (12,)
     assert ses.plans >= 2            # initial plan + >=1 window replan
@@ -329,8 +335,8 @@ def test_session_drives_rolling_replay():
 
 def test_session_seed_installs_external_incumbent():
     inst = random_instance(6, 6, 10, seed=1)
-    res = plan("agh", instance=inst, options=PlanOptions(workers=0))
-    ses = PlanSession(options=PlanOptions(workers=0))
+    res = plan("agh", instance=inst)
+    ses = PlanSession()
     ses.seed(inst, res)
     _assert_bitwise_equal(ses.incumbent, res.solution, "seed")
     r1 = ses.replan(lam=inst.lam * 1.05)

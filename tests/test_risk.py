@@ -20,8 +20,7 @@ import numpy as np
 import pytest
 
 from repro.core import agh, gh, random_instance
-from repro.core.instance import ScenarioBatch
-from repro.core.stage2 import HAVE_HIGHSPY, Stage2System
+from repro.core.stage2 import Stage2System
 from repro.risk import RiskReport, rank_deployments, risk_evaluate
 from repro.risk.api import PROTOCOL
 from repro.risk.metrics import var_cvar
@@ -389,15 +388,7 @@ def test_coefficient_batch_bit_identical_to_scalar(inst, deploy):
                           np.ones((batch.S, system.nnz_all - system.nnz)))
 
 
-# -- satellites: highspy gate, lint scopes ------------------------------
-
-@pytest.mark.skipif(HAVE_HIGHSPY,
-                    reason="highspy installed: warm start is available")
-def test_warm_start_requires_highspy(inst, deploy):
-    system = Stage2System(inst, deploy)
-    with pytest.raises(RuntimeError, match="highspy"):
-        system.solve_batch(ScenarioBatch(S=2), warm_start=True)
-
+# -- satellites: lint scopes --------------------------------------------
 
 def test_lint_scopes_cover_risk_subsystem():
     """src/repro/risk/ is an f64 LP tier like the xla engine: the dtype

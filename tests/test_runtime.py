@@ -1,22 +1,21 @@
-"""Process-level JAX state: the compile-cache policy and the no-fork rule.
+"""Process-level state: the compile-cache policy and one process per solve.
 
 Importing a library module sets no compile-cache option; the entry
 points call `repro.runtime.use_compile_cache()`, which honours
 ``JAX_COMPILATION_CACHE_DIR`` and otherwise uses the checkout's
-``.jax_cache/``.  A process that has initialised a JAX backend may hold
-the chip, so the multi-start pool and table2's grid pool run inline in
-it instead of forking — with the same results.
+``.jax_cache/``.  Planning, evaluation and the Table-2 grid run in the
+calling process: none of them starts a worker process.
 """
 import os
 import subprocess
 import sys
 from pathlib import Path
 
-import numpy as np
 import pytest
 
-from repro.core import agh, objective, random_instance
-from repro.runtime import CACHE_DIR, backend_initialized
+from repro.core import agh, evaluate, random_instance
+from repro.planner import plan
+from repro.runtime import CACHE_DIR
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -54,38 +53,31 @@ def test_use_compile_cache_directory(tmp_path, from_env):
     assert CACHE_DIR == ROOT / ".jax_cache"
 
 
-def _refuse_pools(monkeypatch):
+def _plan():
+    plan("agh", instance=random_instance(6, 6, 10, seed=1))
+
+
+def _evaluate():
+    inst = random_instance(6, 6, 10, seed=1)
+    evaluate(inst, agh(inst), S=8, seed=1)
+
+
+def _table2():
+    from benchmarks import table2
+    table2.run(S=2)
+
+
+@pytest.mark.parametrize("run", [_plan, _evaluate, _table2],
+                         ids=["plan-agh", "evaluate", "table2"])
+def test_planning_starts_no_process(monkeypatch, run):
     import concurrent.futures as cf
+    import multiprocessing
 
     def refuse(*args, **kwargs):
-        raise AssertionError("a process holding a JAX backend forked")
+        raise AssertionError("a solve started a worker process")
 
     monkeypatch.setattr(cf, "ProcessPoolExecutor", refuse)
-
-
-def test_multi_start_runs_inline_once_a_backend_is_live(monkeypatch):
-    import jax
-    jax.devices()
-    assert backend_initialized()
-    _refuse_pools(monkeypatch)
-    inst = random_instance(6, 6, 10, seed=1)
-    stats = {}
-    pooled = agh(inst, workers=4, stats=stats)
-    assert stats["multi_start_inline"] is True
-    inline = agh(inst, workers=1)
-    assert objective(inst, pooled) == objective(inst, inline)
-    assert np.array_equal(pooled.x, inline.x)
-
-
-def test_table2_grid_runs_inline_once_a_backend_is_live(monkeypatch):
-    import jax
-    jax.devices()
-    _refuse_pools(monkeypatch)
+    monkeypatch.setattr(multiprocessing, "Process", refuse)
+    monkeypatch.setattr(multiprocessing.process.BaseProcess, "start", refuse)
     monkeypatch.syspath_prepend(str(ROOT))
-    from benchmarks import table2
-
-    def rows(workers):
-        return [{k: v for k, v in r.items() if k != "plan_s"}
-                for r in table2.run(S=2, workers=workers)]
-
-    assert rows(2) == rows(None)
+    run()

@@ -135,15 +135,6 @@ def test_evaluate_strict_cap_paths_agree():
     assert np.allclose(rb.per_scenario_cost, rl.per_scenario_cost, atol=1e-6)
 
 
-def test_evaluate_process_pool_matches_serial():
-    inst = default_instance()
-    deploy = gh(inst)
-    rs = evaluate(inst, deploy, S=8, seed=1)
-    rw = evaluate(inst, deploy, S=8, seed=1, workers=2)
-    assert rs.violation_rate == rw.violation_rate
-    assert np.array_equal(rs.per_scenario_cost, rw.per_scenario_cost)
-
-
 def test_ssm_models_match_reference():
     """kv_applicable=False models get no (8f) row (constant recurrent
     state, not per-token KV) — the factored assembly must mirror the seed's
