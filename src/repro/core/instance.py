@@ -23,6 +23,10 @@ PRECISIONS = ("FP16", "INT8", "INT4")
 NU = {"FP16": 1.0, "INT8": 0.5, "INT4": 0.25}     # latency / bytes-per-weight scale
 MU = {"FP16": 1.0, "INT8": 1.15, "INT4": 1.35}    # error multiplier
 
+# Bytes of the scratch block of uniforms that `Instance.perturbed_batch`
+# draws at a time (1,191 scenarios at I = J = 20).
+_DRAW_BLOCK_BYTES = 4 << 20
+
 # Hardware table: (memory GB, TFLOP/s, HBM bandwidth GB/s, $/h at FP16).
 # Values follow the paper's footnote ranges (24–80 GB, 768–3350 GB/s,
 # 40.7–1484 TFLOPs, $0.35–$2.50/h).
@@ -310,19 +314,39 @@ class Instance:
                         lam_pm: float = 0.20) -> "ScenarioBatch":
         """S Stage-2 scenarios as stacked parameter tensors.
 
-        Draws are taken scenario by scenario in exactly the order
-        `perturbed` uses, so with the same generator the s-th row is
-        bit-identical to the s-th sequential `perturbed` call — the batched
-        and looped evaluation protocols sample the same scenarios.
+        The s-th row is bit-identical to the s-th of S sequential `perturbed`
+        calls on the same generator, which is left in the same state, so the
+        batched and looped evaluation protocols sample the same scenarios.
+        `rng.uniform(low, high, n)` is `low + (high - low) * u` for n doubles
+        u of the stream that `rng.random` fills in C order; so one block of
+        `2I + I·J` doubles per scenario, sliced per field, replays the three
+        calls, and the in-place arithmetic below repeats their expression
+        term for term (IEEE + and × commute exactly).  The block is drawn
+        one sub-block of at most `_DRAW_BLOCK_BYTES` at a time: the
+        transient beyond the outputs is that scratch block, never an
+        [S, 2I + I·J] array.
         """
         I, J = self.I, self.J
+        IJ = I * J
+        per = 2 * I + IJ
         tau = np.empty((S, I))
         e_base = np.empty((S, I, J))
         lam = np.empty((S, I))
-        for s in range(S):
-            tau[s] = self.tau * (1.0 + rng.uniform(0.0, d_infl, I))
-            e_base[s] = self.e_base * (1.0 + rng.uniform(0.0, e_infl, (I, J)))
-            lam[s] = self.lam * (1.0 + rng.uniform(-lam_pm, lam_pm, I))
+        u = np.empty((max(1, min(S, _DRAW_BLOCK_BYTES // (8 * per))), per))
+        fields = ((tau, self.tau, 0, I, 0.0, d_infl),
+                  (e_base.reshape(S, IJ), self.e_base.reshape(IJ),
+                   I, I + IJ, 0.0, e_infl),
+                  (lam, self.lam, I + IJ, per, -lam_pm, lam_pm))
+        for s0 in range(0, S, len(u)):
+            n = min(len(u), S - s0)
+            rng.random(out=u[:n])
+            for out, base, c0, c1, low, high in fields:
+                low, high = float(low), float(high)
+                x = out[s0:s0 + n]
+                np.multiply(u[:n, c0:c1], high - low, out=x)
+                x += low
+                x += 1.0
+                x *= base
         return ScenarioBatch(S=S, tau=tau, e_base=e_base, lam=lam)
 
     def perturbed_chunks(self, rng: np.random.Generator, S: int,
@@ -335,7 +359,10 @@ class Instance:
         concatenating the chunks is bit-identical to the one-shot
         `perturbed_batch(rng, S)` — but peak memory is O(chunk·I·J) instead
         of O(S·I·J), which is what lets `risk_evaluate` run S=10⁵ without a
-        ~GB e_base allocation.  Pinned in tests/test_risk.py.
+        ~GB e_base allocation.  Each chunk is drawn as `perturbed_batch`
+        draws, one generator block per sub-block of scenarios, so the
+        transient on top of a chunk's outputs stays within
+        `_DRAW_BLOCK_BYTES`.  Pinned in tests/test_risk.py.
         """
         done = 0
         while done < S:

@@ -78,20 +78,71 @@ def test_stage2_system_reuse_matches_one_shot():
         assert abs(r.cost - want) <= 1e-9 * max(1.0, abs(want))
 
 
-def test_perturbed_batch_matches_sequential_draws():
-    """Batched sampling must replay the sequential RNG stream bitwise."""
-    inst = default_instance()
-    batch = inst.perturbed_batch(np.random.default_rng(42), 5,
-                                 d_infl=0.15, e_infl=0.10, lam_pm=0.20)
+PROTOCOL = dict(d_infl=0.15, e_infl=0.10, lam_pm=0.20)
+FAMILIES = {"protocol": PROTOCOL,
+            "lam_pm=0": dict(PROTOCOL, lam_pm=0.0),
+            "d_infl=0": dict(PROTOCOL, d_infl=0.0)}
+SUB = 7       # scenarios per generator sub-block in the cases below
+
+
+def _draw_cases():
+    cases = [pytest.param("default", 5, "protocol", None,
+                          id="default-S5-materialize")]
+    for S in (1, SUB - 1, SUB, SUB + 1, SUB * 5 // 2):
+        for fam in FAMILIES:
+            cases.append(pytest.param("8x5", S, fam, SUB,
+                                      id=f"8x5-S{S}-{fam}"))
+    return cases
+
+
+@pytest.mark.parametrize("which,S,fam,sub", _draw_cases())
+def test_perturbed_batch_matches_sequential_draws(which, S, fam, sub,
+                                                  monkeypatch):
+    """Batched sampling must replay the sequential RNG stream bitwise,
+    across sub-block boundaries (I != J catches a transposed reshape), and
+    leave the generator where S sequential draws leave it."""
+    import repro.core.instance as instance_mod
+    inst = (default_instance() if which == "default"
+            else random_instance(8, 5, 6, seed=2))
+    if sub is not None:
+        per = 2 * inst.I + inst.I * inst.J
+        monkeypatch.setattr(instance_mod, "_DRAW_BLOCK_BYTES", 8 * per * sub)
+    kw = FAMILIES[fam]
+    rng_b = np.random.default_rng(42)
+    batch = inst.perturbed_batch(rng_b, S, **kw)
     rng = np.random.default_rng(42)
-    for s in range(5):
-        scen = inst.perturbed(rng, d_infl=0.15, e_infl=0.10, lam_pm=0.20)
-        assert np.array_equal(batch.tau[s], scen.tau)
-        assert np.array_equal(batch.e_base[s], scen.e_base)
-        assert np.array_equal(batch.lam[s], scen.lam)
-        mat = batch.materialize(inst, s)
-        assert np.array_equal(mat.lam, scen.lam)
-        assert np.array_equal(mat.D_cfg, scen.D_cfg)
+    for s in range(S):
+        scen = inst.perturbed(rng, **kw)
+        assert np.array_equal(batch.tau[s], scen.tau), s
+        assert np.array_equal(batch.e_base[s], scen.e_base), s
+        assert np.array_equal(batch.lam[s], scen.lam), s
+        if sub is None:
+            mat = batch.materialize(inst, s)
+            assert np.array_equal(mat.lam, scen.lam)
+            assert np.array_equal(mat.D_cfg, scen.D_cfg)
+    assert np.array_equal(rng_b.random(4), rng.random(4))
+
+
+def test_perturbed_batch_transient_is_one_sub_block():
+    """The draw's peak allocation beyond its three outputs is the scratch
+    block of uniforms, not an [S, 2I + I·J] array of them."""
+    import tracemalloc
+
+    from repro.core.instance import _DRAW_BLOCK_BYTES
+    inst = random_instance(20, 20, 20, seed=42)
+    S, per = 4096, 2 * 20 + 20 * 20
+    block = min(S, _DRAW_BLOCK_BYTES // (8 * per)) * per * 8
+    assert block < S * per * 8 // 2      # the test can tell the two apart
+    rng = np.random.default_rng(3)
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        batch = inst.perturbed_batch(rng, S, **PROTOCOL)
+        peak = tracemalloc.get_traced_memory()[1] - base
+    finally:
+        tracemalloc.stop()
+    outputs = batch.tau.nbytes + batch.e_base.nbytes + batch.lam.nbytes
+    assert peak - outputs < block + (256 << 10), (peak, outputs, block)
 
 
 def test_evaluate_batched_matches_loop():
